@@ -12,7 +12,10 @@ the old complete file or the new complete file, never a torn one.
 corrupted/truncated file instead of surfacing a zipfile traceback, and
 ``latest_checkpoint``/``list_checkpoints`` discover cadence-numbered
 checkpoints (``<prefix><n>.npz``) so a resuming service can fall back to
-the newest VALID file.
+the newest VALID file.  Reading a checkpoint into host arrays needs no
+JAX (only ``save_pytree`` on device arrays and ``load_pytree(target=)``
+import it), so a supervising process can inspect checkpoints without
+touching the accelerator.
 
 Service checkpoint schema (``repro.launch.service``, version 1) — a
 nested pytree saved through this module:
@@ -42,7 +45,6 @@ import re
 import zipfile
 from typing import Any, List, Optional
 
-import jax
 import numpy as np
 
 
@@ -63,6 +65,7 @@ def _flatten(tree) -> dict:
         elif node is None:
             flat[prefix + "#none"] = np.zeros((), np.int8)
         else:
+            import jax
             flat[prefix] = np.asarray(jax.device_get(node))
 
     rec("", tree)
@@ -167,6 +170,7 @@ def load_pytree(path: str, target: Any = None):
             f"earlier checkpoint (see list_checkpoints).") from e
 
     if target is not None:
+        import jax
         leaves, treedef = jax.tree.flatten(target)
         keys = sorted(flat)
         assert len(keys) == len(leaves), (len(keys), len(leaves))

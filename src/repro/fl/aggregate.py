@@ -59,23 +59,22 @@ from repro.launch.mesh import DATA_AXIS, MODEL_AXIS
 from repro.parallel.sharding import (flat_buffer_col_spec,
                                      flat_buffer_row_spec, flat_buffer_spec)
 
-# jax.shard_map only exists on newer JAX; fall back to the experimental
-# home (0.4.x).  repro.fl.spmd shares this resolved symbol.
-shard_map = getattr(jax, "shard_map", None)
-if shard_map is None:
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+
+def _shard_map_novma(fn, mesh, in_specs, out_specs):
+    """shard_map without varying-axes checking: a ``pallas_call`` output
+    carries no vma annotation, so the check would reject the kernel
+    bodies; the aggregation bodies are checked by parity tests instead."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
-def _shard_map_norep(fn, mesh, in_specs, out_specs):
-    """shard_map without replication checking: pallas_call has no
-    replication rule on 0.4.x, and the aggregation bodies are checked by
-    parity tests instead."""
-    try:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-    except TypeError:                      # newer API dropped check_rep
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs)
+def weighted_sum(weights, buf):
+    """``sum_n weights[n] * buf[n]`` in fp32 over the leading axis: the
+    numerator of eqs. 6 and 10.  The contraction is asked for at fp32
+    precision, since the TPU's default rounds f32 matmul operands to
+    bf16 and these are exact weighted means of model weights."""
+    return jnp.tensordot(weights, buf.astype(jnp.float32), axes=1,
+                         precision=jax.lax.Precision.HIGHEST)
 
 
 def _select_kernel(use_kernel: Optional[bool]) -> bool:
@@ -101,7 +100,7 @@ def weighted_average(params_list: Sequence, weights: Sequence[float]):
 
     def avg(*leaves):
         stack = jnp.stack([l.astype(jnp.float32) for l in leaves])
-        out = jnp.tensordot(w, stack, axes=1)
+        out = weighted_sum(w, stack)
         return out.astype(leaves[0].dtype)
 
     return jax.tree.map(avg, *params_list)
@@ -153,8 +152,7 @@ def _cloud_body(buf, weights, kernel: bool, blk_f: int):
     """Single-slab cloud aggregation (eq. 10): mean + broadcast-back."""
     if kernel:
         return hier_cloud_aggregate(buf, weights, blk_f=blk_f)
-    mean = jnp.tensordot(weights, buf.astype(jnp.float32),
-                         axes=1) / jnp.sum(weights)
+    mean = weighted_sum(weights, buf) / jnp.sum(weights)
     return jnp.broadcast_to(mean[None], buf.shape).astype(jnp.float32)
 
 
@@ -200,7 +198,6 @@ def flat_cloud_aggregate(buf, weights, *, use_kernel: Optional[bool] = None,
             return _cloud_body(b, w, kernel, blk)
     else:
         def local_fn(b, w):
-            b32 = b.astype(jnp.float32)
             den = jnp.sum(w)
             if kernel:
                 # local weighted mean * local weight sum = local weighted
@@ -208,11 +205,11 @@ def flat_cloud_aggregate(buf, weights, *, use_kernel: Optional[bool] = None,
                 num = jnp.where(den > 0,
                                 hier_aggregate(b, w, blk_f=blk) * den, 0.0)
             else:
-                num = jnp.tensordot(w, b32, axes=1)
+                num = weighted_sum(w, b)
             mean = psum_weighted_mean(num, den, DATA_AXIS)
             return jnp.broadcast_to(mean[None], b.shape).astype(jnp.float32)
 
-    fn = _shard_map_norep(local_fn, mesh, (spec, row_spec), spec)
+    fn = _shard_map_novma(local_fn, mesh, (spec, row_spec), spec)
     return fn(buf, weights)
 
 
@@ -245,7 +242,7 @@ def flat_edge_aggregate(buf, weights, group_ids, num_groups: int, *,
     def local_fn(b, w, g):
         return _edge_body(b, w, g, ng, kernel, blk)
 
-    fn = _shard_map_norep(local_fn, mesh, (spec, row_spec, row_spec), spec)
+    fn = _shard_map_novma(local_fn, mesh, (spec, row_spec, row_spec), spec)
     return fn(buf, weights, group_ids)
 
 
@@ -275,7 +272,7 @@ def flat_staleness_merge(global_vec, buf, eff_weights, w_total, *, mesh=None):
     w_total = float(w_total)
     g32 = global_vec.astype(jnp.float32)
     if mesh is None or _trivial_mesh(mesh):
-        num = jnp.tensordot(eff_weights, buf.astype(jnp.float32), axes=1)
+        num = weighted_sum(eff_weights, buf)
         lam = jnp.sum(eff_weights) / w_total
         return (1.0 - lam) * g32 + num / w_total
 
@@ -286,16 +283,16 @@ def flat_staleness_merge(global_vec, buf, eff_weights, w_total, *, mesh=None):
 
     if nd == 1:
         def local_fn(g, b, w):
-            num = jnp.tensordot(w, b.astype(jnp.float32), axes=1)
+            num = weighted_sum(w, b)
             lam = jnp.sum(w) / w_total
             return (1.0 - lam) * g + num / w_total
     else:
         def local_fn(g, b, w):
-            num = jnp.tensordot(w, b.astype(jnp.float32), axes=1)
+            num = weighted_sum(w, b)
             return psum_staleness_merge(g, num, jnp.sum(w), w_total,
                                         DATA_AXIS)
 
-    fn = _shard_map_norep(local_fn, mesh, (col_spec, spec, row_spec),
+    fn = _shard_map_novma(local_fn, mesh, (col_spec, spec, row_spec),
                           col_spec)
     return fn(g32, buf, eff_weights)
 

@@ -73,4 +73,5 @@ def global_gradient(loss_fn: Callable, stacked_params, stacked_batch, weights):
         lambda q: loss_fn(q, b)[0])(p))(stacked_params, stacked_batch)
     w = weights / jnp.sum(weights)
     return jax.tree.map(
-        lambda g: jnp.tensordot(w, g.astype(jnp.float32), axes=1), grads)
+        lambda g: jnp.tensordot(w, g.astype(jnp.float32), axes=1,
+                                precision=jax.lax.Precision.HIGHEST), grads)

@@ -13,7 +13,7 @@ the true D_n still drives both the aggregation weights and the clock).
 
 Hot-loop layout: the UE replicas live in ONE flat (N, F_total) fp32
 buffer (``repro.fl.flatten``); the whole b-iteration edge loop carries
-the buffer (donated on accelerator backends) and every aggregation event
+the buffer (donated, so it is updated in place) and every aggregation event
 is a single fused dispatch (``repro.fl.aggregate.flat_*``).  Pytrees are
 materialized only at train/eval/checkpoint boundaries.
 
@@ -196,11 +196,12 @@ class HFLSimulator:
             resample.append(rng.choice(m, size=k, replace=m < k)
                             if m != k else np.arange(k))
         stacked = {
-            key: jnp.asarray(np.stack([d[key][ix] for d, ix in
-                                       zip(ue_data, resample)]))
+            key: np.stack([d[key][ix] for d, ix in zip(ue_data, resample)])
             for key in ue_data[0]
         }
-        self.batches = stacked                       # leaves (N, k, ...)
+        # leaves (N, k, ...); on the device unless a mesh shards them below
+        self.batches = (stacked if mesh is not None else
+                        jax.tree.map(jnp.asarray, stacked))
 
         # Aggregation weights: the paper's D_n (eq. 6/10).
         if schedule.problem is not None:
@@ -254,7 +255,8 @@ class HFLSimulator:
         self._weighted_ops_cache = None
         if fault_model is not None or sampler is not None:
             self._weighted_ops()    # build eagerly for fault/sampled runs
-        # Weight-averaged train loss over ALL UEs (one vmap'd loss).
+        # Weight-averaged train loss over ALL UEs (one vmap'd loss over the
+        # hot rows; mesh padding rows carry zero weight).
         self._train_loss = jax.jit(
             lambda gp, batches, w: jnp.sum(
                 (w / jnp.sum(w)) *
@@ -314,11 +316,8 @@ class HFLSimulator:
             flat = jax.lax.fori_loop(0, b, edge_round, flat)
             return aggregate.flat_cloud_aggregate(flat, weights, mesh=mesh)
 
-        # Donate the flat buffer so the cloud round updates it in place
-        # (donation is a no-op warning on CPU, so only request it where
-        # the runtime honors it).
-        donate = (0,) if jax.default_backend() in ("tpu", "gpu") else ()
-        return jax.jit(cloud_round, donate_argnums=donate)
+        # Donate the flat buffer so the cloud round updates it in place.
+        return jax.jit(cloud_round, donate_argnums=0)
 
     def _build_async_ops(self):
         """Jitted bodies of the async event replay (mode='async').
@@ -366,8 +365,7 @@ class HFLSimulator:
             return aggregate.flat_staleness_merge(g, flat, eff_weights,
                                                   w_total, mesh=mesh)
 
-        donate = (0,) if jax.default_backend() in ("tpu", "gpu") else ()
-        return (jax.jit(depart_cycle, donate_argnums=donate), jax.jit(merge))
+        return (jax.jit(depart_cycle, donate_argnums=0), jax.jit(merge))
 
     def _build_faulty_ops(self):
         """Fault-aware twins of the hot-loop closures (``fault_model=``).
@@ -422,9 +420,8 @@ class HFLSimulator:
             new = jax.lax.fori_loop(0, b, edge_round, seeded)
             return jnp.where(mask[:, None], new, flat)
 
-        donate = (0,) if jax.default_backend() in ("tpu", "gpu") else ()
-        return (jax.jit(faulty_cloud_round, donate_argnums=donate),
-                jax.jit(faulty_depart, donate_argnums=donate))
+        return (jax.jit(faulty_cloud_round, donate_argnums=0),
+                jax.jit(faulty_depart, donate_argnums=0))
 
     def _fault_survivor_matrix(self, fc):
         """``fc.survivors`` mapped onto the HOT row layout."""
@@ -481,7 +478,7 @@ class HFLSimulator:
     def global_params(self):
         """The cloud model: weighted mean over UE replicas (eq. 10)."""
         w = self._hot_weights / jnp.sum(self._hot_weights)
-        mean = jnp.tensordot(w, self._flat, axes=1)      # (f_padded,)
+        mean = aggregate.weighted_sum(w, self._flat)     # (f_padded,)
         return self._layout.unravel_single(mean[:self._layout.total])
 
     def _weighted_ops(self):
@@ -505,8 +502,8 @@ class HFLSimulator:
         """(F_hot,) f32 cloud model vector: the weighted mean of the
         current flat buffer (sharded to the column spec under a mesh)."""
         w_np = np.asarray(self._hot_weights)
-        g = jnp.tensordot(jnp.asarray(w_np / w_np.sum(), jnp.float32),
-                          self._flat, axes=1)
+        g = aggregate.weighted_sum(
+            jnp.asarray(w_np / w_np.sum(), jnp.float32), self._flat)
         return self.place_cloud_vector(g)
 
     def place_cloud_vector(self, g):
@@ -637,7 +634,8 @@ class HFLSimulator:
             if (r + 1) % eval_every == 0 or r == rounds - 1:
                 gp = self.global_params()
                 loss, mets = self.loss_fn(gp, test_batch)
-                trl = self._train_loss(gp, self.batches, self.weights)
+                trl = self._train_loss(gp, self._hot_batches,
+                                       self._hot_weights)
                 times.append(clock)
                 accs.append(float(mets.get("acc", jnp.nan)))
                 tlosses.append(float(loss))
@@ -692,7 +690,8 @@ class HFLSimulator:
             if (r + 1) % eval_every == 0 or r == rounds - 1:
                 gp = self.global_params()
                 loss, mets = self.loss_fn(gp, test_batch)
-                trl = self._train_loss(gp, self.batches, self.weights)
+                trl = self._train_loss(gp, self._hot_batches,
+                                       self._hot_weights)
                 times.append(clock)
                 accs.append(float(mets.get("acc", jnp.nan)))
                 tlosses.append(float(loss))
@@ -758,7 +757,8 @@ class HFLSimulator:
             if (r + 1) % eval_every == 0 or r == rounds - 1:
                 gp = self.global_params()
                 loss, mets = self.loss_fn(gp, test_batch)
-                trl = self._train_loss(gp, self.batches, self.weights)
+                trl = self._train_loss(gp, self._hot_batches,
+                                       self._hot_weights)
                 times.append(clock)
                 accs.append(float(mets.get("acc", jnp.nan)))
                 tlosses.append(float(loss))
@@ -871,7 +871,8 @@ class HFLSimulator:
             if updates_seen % eval_every == 0 or updates_seen == num_updates:
                 gp = self.global_from_vector(g)
                 loss, mets = self.loss_fn(gp, test_batch)
-                trl = self._train_loss(gp, self.batches, self.weights)
+                trl = self._train_loss(gp, self._hot_batches,
+                                       self._hot_weights)
                 times.append(ev.t)
                 accs.append(float(mets.get("acc", jnp.nan)))
                 tlosses.append(float(loss))

@@ -24,11 +24,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.fl import clients
-from repro.fl.aggregate import psum_weighted_mean, shard_map as _shard_map
-
-# jax.lax.pvary only exists on newer JAX; on 0.4.x psum results need no
-# re-marking.
-_pvary = getattr(jax.lax, "pvary", lambda x, axes: x)
+from repro.fl.aggregate import psum_weighted_mean
 
 
 def stack_for_mesh(params, num_edges: int, ues_per_edge: int):
@@ -75,17 +71,17 @@ def make_hfl_cloud_round(loss_fn: Callable, mesh, *, a: int, b: int,
             else:
                 q = local_gd(q, batch)
             q = wavg(q, "ue")                             # eq. (6)
-            # On new JAX the psum over 'ue' erases the 'ue' varying mark;
-            # restore it so the fori_loop carry keeps a stable type
-            # (no-op on 0.4.x, which has no varying marks).
-            return jax.tree.map(lambda x: _pvary(x, ("ue",)), q)
+            # The psum over 'ue' erases the 'ue' varying mark; restore it
+            # so the fori_loop carry keeps a stable type.
+            return jax.tree.map(
+                lambda x: jax.lax.pcast(x, ("ue",), to="varying"), q)
 
         q = jax.lax.fori_loop(0, b, edge_round, p)
         q = wavg(q, ("edge", "ue"))                       # eq. (10)
         return jax.tree.map(lambda x: x[None], q)
 
     spec_ue = P(("edge", "ue"))
-    fn = _shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(spec_ue, spec_ue, spec_ue),
         out_specs=spec_ue)

@@ -24,6 +24,14 @@ carrying partial segment sums when client-blocking (N > MAX_N_UNBLOCKED)
 kicks in: the grid grows a two-step phase axis — phase 0 accumulates
 segment sums over client blocks, phase 1 scatters the means back — so one
 aggregation event stays ONE pallas_call at every size.
+
+Every operand and output is 2-D: weights travel as a ``(1, N)`` row (the
+segment kernels) or an ``(N, 1)`` column (the cloud kernels), per-group
+sums as ``(M, 1)``, the reduce-only mean as ``(1, F)``.  Mosaic tiles a
+1-D f32 array differently from XLA once it outgrows one tile, so a 1-D
+block of a larger array does not compile for the chip.  The matmuls ask
+for fp32 contraction: eqs. 6 and 10 are exact weighted means, and a
+bf16 pass would round them.
 """
 from __future__ import annotations
 
@@ -35,12 +43,17 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 MAX_N_UNBLOCKED = 512
+_FP32 = jax.lax.Precision.HIGHEST
+
+
+def _dot(a, b):
+    return jnp.dot(a, b, precision=_FP32, preferred_element_type=jnp.float32)
 
 
 def _agg_kernel(x_ref, w_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)          # (N, blk_f)
-    w = w_ref[...].astype(jnp.float32)          # (N,)
-    o_ref[...] = (w[:, None] * x).sum(0) / w.sum()
+    w = w_ref[...].astype(jnp.float32)          # (N, 1)
+    o_ref[...] = (w * x).sum(0, keepdims=True) / w.sum()
 
 
 def _agg_kernel_blocked(x_ref, w_ref, o_ref, acc_ref, *, n_n: int):
@@ -51,8 +64,8 @@ def _agg_kernel_blocked(x_ref, w_ref, o_ref, acc_ref, *, n_n: int):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     x = x_ref[...].astype(jnp.float32)          # (blk_n, blk_f)
-    w = w_ref[...].astype(jnp.float32)          # (blk_n,) zero-padded
-    acc_ref[...] += (w[:, None] * x).sum(0)
+    w = w_ref[...].astype(jnp.float32)          # (blk_n, 1) zero-padded
+    acc_ref[...] += (w * x).sum(0, keepdims=True)
 
     @pl.when(ni == n_n - 1)
     def _finish():
@@ -65,19 +78,21 @@ def hier_aggregate_2d(x, w, *, blk_f: int = 512, blk_n: int = 256,
     N, F = x.shape
     blk_f = min(blk_f, F)
     n_f = pl.cdiv(F, blk_f)
+    w = w.astype(jnp.float32)
 
     if N <= MAX_N_UNBLOCKED:
-        return pl.pallas_call(
+        out = pl.pallas_call(
             _agg_kernel,
             grid=(n_f,),
             in_specs=[
                 pl.BlockSpec((N, blk_f), lambda fi: (0, fi)),
-                pl.BlockSpec((N,), lambda fi: (0,)),
+                pl.BlockSpec((N, 1), lambda fi: (0, 0)),
             ],
-            out_specs=pl.BlockSpec((blk_f,), lambda fi: (fi,)),
-            out_shape=jax.ShapeDtypeStruct((F,), jnp.float32),
+            out_specs=pl.BlockSpec((1, blk_f), lambda fi: (0, fi)),
+            out_shape=jax.ShapeDtypeStruct((1, F), jnp.float32),
             interpret=interpret,
-        )(x, w)
+        )(x, w[:, None])
+        return out[0]
 
     blk_n = min(blk_n, N)
     n_n = pl.cdiv(N, blk_n)
@@ -86,20 +101,20 @@ def hier_aggregate_2d(x, w, *, blk_f: int = 512, blk_n: int = 256,
         # zero weights make the padded client rows contribute nothing
         x = jnp.pad(x, ((0, pad_n), (0, 0)))
         w = jnp.pad(w, (0, pad_n))
-    wsum = jnp.sum(w.astype(jnp.float32))
+    wsum = jnp.sum(w)
     out = pl.pallas_call(
         functools.partial(_agg_kernel_blocked, n_n=n_n),
         grid=(n_f, n_n),
         in_specs=[
             pl.BlockSpec((blk_n, blk_f), lambda fi, ni: (ni, fi)),
-            pl.BlockSpec((blk_n,), lambda fi, ni: (ni,)),
+            pl.BlockSpec((blk_n, 1), lambda fi, ni: (ni, 0)),
         ],
-        out_specs=pl.BlockSpec((blk_f,), lambda fi, ni: (fi,)),
-        out_shape=jax.ShapeDtypeStruct((F,), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((blk_f,), jnp.float32)],
+        out_specs=pl.BlockSpec((1, blk_f), lambda fi, ni: (0, fi)),
+        out_shape=jax.ShapeDtypeStruct((1, F), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((1, blk_f), jnp.float32)],
         interpret=interpret,
-    )(x, w)
-    return out / wsum
+    )(x, w[:, None])
+    return out[0] / wsum
 
 
 # ---------------------------------------------------------------------------
@@ -109,9 +124,9 @@ def hier_aggregate_2d(x, w, *, blk_f: int = 512, blk_n: int = 256,
 
 def _bcast_kernel(x_ref, w_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)          # (N, blk_f)
-    w = w_ref[...].astype(jnp.float32)          # (N,)
-    mean = (w[:, None] * x).sum(0) / w.sum()
-    o_ref[...] = jnp.broadcast_to(mean[None], o_ref.shape)
+    w = w_ref[...].astype(jnp.float32)          # (N, 1)
+    mean = (w * x).sum(0, keepdims=True) / w.sum()
+    o_ref[...] = jnp.broadcast_to(mean, o_ref.shape)
 
 
 def hier_bcast_aggregate_2d(x, w, *, blk_f: int = 512,
@@ -122,9 +137,10 @@ def hier_bcast_aggregate_2d(x, w, *, blk_f: int = 512,
     Large N falls through to the segment kernel with a single group.
     """
     N, F = x.shape
+    w = w.astype(jnp.float32)
     if N > MAX_N_UNBLOCKED:
         onehot = jnp.ones((1, N), jnp.float32)
-        gw = jnp.sum(w.astype(jnp.float32))[None]
+        gw = jnp.sum(w)[None]
         return hier_segment_aggregate_2d(x, w, onehot, gw, blk_f=blk_f,
                                          interpret=interpret)
     blk_f = min(blk_f, F)
@@ -134,24 +150,21 @@ def hier_bcast_aggregate_2d(x, w, *, blk_f: int = 512,
         grid=(n_f,),
         in_specs=[
             pl.BlockSpec((N, blk_f), lambda fi: (0, fi)),
-            pl.BlockSpec((N,), lambda fi: (0,)),
+            pl.BlockSpec((N, 1), lambda fi: (0, 0)),
         ],
         out_specs=pl.BlockSpec((N, blk_f), lambda fi: (0, fi)),
         out_shape=jax.ShapeDtypeStruct((N, F), jnp.float32),
         interpret=interpret,
-    )(x, w)
+    )(x, w[:, None])
 
 
 def _seg_kernel(x_ref, w_ref, oh_ref, gw_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)          # (N, blk_f)
-    w = w_ref[...].astype(jnp.float32)          # (N,)
+    w = w_ref[...].astype(jnp.float32)          # (1, N)
     oh = oh_ref[...]                            # (M, N) one-hot membership
-    gw = gw_ref[...]                            # (M,) per-group weight sums
-    acc = jnp.dot(oh * w[None, :], x,
-                  preferred_element_type=jnp.float32)        # (M, blk_f)
-    mean = acc / jnp.maximum(gw, 1e-12)[:, None]
-    o_ref[...] = jnp.dot(oh.T, mean,
-                         preferred_element_type=jnp.float32)  # (N, blk_f)
+    gw = gw_ref[...]                            # (M, 1) per-group weight sums
+    mean = _dot(oh * w, x) / jnp.maximum(gw, 1e-12)          # (M, blk_f)
+    o_ref[...] = _dot(oh.T, mean)                            # (N, blk_f)
 
 
 def _seg_kernel_blocked(x_ref, w_ref, oh_ref, gw_ref, o_ref, acc_ref):
@@ -163,20 +176,17 @@ def _seg_kernel_blocked(x_ref, w_ref, oh_ref, gw_ref, o_ref, acc_ref):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     x = x_ref[...].astype(jnp.float32)          # (blk_n, blk_f)
-    w = w_ref[...].astype(jnp.float32)          # (blk_n,) zero-padded
+    w = w_ref[...].astype(jnp.float32)          # (1, blk_n) zero-padded
     oh = oh_ref[...]                            # (M, blk_n)
 
     @pl.when(ph == 0)
     def _accumulate():
-        acc_ref[...] += jnp.dot(oh * w[None, :], x,
-                                preferred_element_type=jnp.float32)
+        acc_ref[...] += _dot(oh * w, x)
 
     @pl.when(ph == 1)
     def _scatter():
-        gw = gw_ref[...]                        # (M,)
-        mean = acc_ref[...] / jnp.maximum(gw, 1e-12)[:, None]
-        o_ref[...] = jnp.dot(oh.T, mean,
-                             preferred_element_type=jnp.float32)
+        gw = gw_ref[...]                        # (M, 1)
+        o_ref[...] = _dot(oh.T, acc_ref[...] / jnp.maximum(gw, 1e-12))
 
 
 def hier_segment_aggregate_2d(x, w, onehot, gw, *, blk_f: int = 512,
@@ -191,6 +201,8 @@ def hier_segment_aggregate_2d(x, w, onehot, gw, *, blk_f: int = 512,
     M = onehot.shape[0]
     blk_f = min(blk_f, F)
     n_f = pl.cdiv(F, blk_f)
+    w = w.astype(jnp.float32)
+    gw = gw.astype(jnp.float32)[:, None]
 
     if N <= MAX_N_UNBLOCKED:
         return pl.pallas_call(
@@ -198,14 +210,14 @@ def hier_segment_aggregate_2d(x, w, onehot, gw, *, blk_f: int = 512,
             grid=(n_f,),
             in_specs=[
                 pl.BlockSpec((N, blk_f), lambda fi: (0, fi)),
-                pl.BlockSpec((N,), lambda fi: (0,)),
+                pl.BlockSpec((1, N), lambda fi: (0, 0)),
                 pl.BlockSpec((M, N), lambda fi: (0, 0)),
-                pl.BlockSpec((M,), lambda fi: (0,)),
+                pl.BlockSpec((M, 1), lambda fi: (0, 0)),
             ],
             out_specs=pl.BlockSpec((N, blk_f), lambda fi: (0, fi)),
             out_shape=jax.ShapeDtypeStruct((N, F), jnp.float32),
             interpret=interpret,
-        )(x, w, onehot, gw)
+        )(x, w[None, :], onehot, gw)
 
     blk_n = min(blk_n, N)
     n_n = pl.cdiv(N, blk_n)
@@ -221,15 +233,15 @@ def hier_segment_aggregate_2d(x, w, onehot, gw, *, blk_f: int = 512,
         grid=(n_f, 2, n_n),
         in_specs=[
             pl.BlockSpec((blk_n, blk_f), lambda fi, ph, ni: (ni, fi)),
-            pl.BlockSpec((blk_n,), lambda fi, ph, ni: (ni,)),
+            pl.BlockSpec((1, blk_n), lambda fi, ph, ni: (0, ni)),
             pl.BlockSpec((M, blk_n), lambda fi, ph, ni: (0, ni)),
-            pl.BlockSpec((M,), lambda fi, ph, ni: (0,)),
+            pl.BlockSpec((M, 1), lambda fi, ph, ni: (0, 0)),
         ],
         out_specs=pl.BlockSpec((blk_n, blk_f), lambda fi, ph, ni: (ni, fi)),
         out_shape=jax.ShapeDtypeStruct((N + pad_n, F), jnp.float32),
         scratch_shapes=[pltpu.VMEM((M, blk_f), jnp.float32)],
         interpret=interpret,
-    )(x, w, onehot, gw)
+    )(x, w[None, :], onehot, gw)
     return out[:N]
 
 
@@ -240,10 +252,8 @@ def hier_segment_aggregate_2d(x, w, onehot, gw, *, blk_f: int = 512,
 
 def _seg_sum_kernel(x_ref, w_ref, oh_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)          # (N, blk_f)
-    w = w_ref[...].astype(jnp.float32)          # (N,)
-    oh = oh_ref[...]                            # (M, N)
-    o_ref[...] = jnp.dot(oh * w[None, :], x,
-                         preferred_element_type=jnp.float32)   # (M, blk_f)
+    w = w_ref[...].astype(jnp.float32)          # (1, N)
+    o_ref[...] = _dot(oh_ref[...] * w, x)       # (M, blk_f)
 
 
 def _seg_sum_kernel_blocked(x_ref, w_ref, oh_ref, o_ref):
@@ -254,10 +264,8 @@ def _seg_sum_kernel_blocked(x_ref, w_ref, oh_ref, o_ref):
         o_ref[...] = jnp.zeros_like(o_ref)
 
     x = x_ref[...].astype(jnp.float32)          # (blk_n, blk_f)
-    w = w_ref[...].astype(jnp.float32)          # (blk_n,) zero-padded
-    oh = oh_ref[...]                            # (M, blk_n)
-    o_ref[...] += jnp.dot(oh * w[None, :], x,
-                          preferred_element_type=jnp.float32)
+    w = w_ref[...].astype(jnp.float32)          # (1, blk_n) zero-padded
+    o_ref[...] += _dot(oh_ref[...] * w, x)      # oh: (M, blk_n)
 
 
 def hier_segment_sum_2d(x, w, onehot, *, blk_f: int = 512,
@@ -277,6 +285,7 @@ def hier_segment_sum_2d(x, w, onehot, *, blk_f: int = 512,
     M = onehot.shape[0]
     blk_f = min(blk_f, F)
     n_f = pl.cdiv(F, blk_f)
+    w = w.astype(jnp.float32)
 
     if N <= MAX_N_UNBLOCKED:
         return pl.pallas_call(
@@ -284,13 +293,13 @@ def hier_segment_sum_2d(x, w, onehot, *, blk_f: int = 512,
             grid=(n_f,),
             in_specs=[
                 pl.BlockSpec((N, blk_f), lambda fi: (0, fi)),
-                pl.BlockSpec((N,), lambda fi: (0,)),
+                pl.BlockSpec((1, N), lambda fi: (0, 0)),
                 pl.BlockSpec((M, N), lambda fi: (0, 0)),
             ],
             out_specs=pl.BlockSpec((M, blk_f), lambda fi: (0, fi)),
             out_shape=jax.ShapeDtypeStruct((M, F), jnp.float32),
             interpret=interpret,
-        )(x, w, onehot)
+        )(x, w[None, :], onehot)
 
     blk_n = min(blk_n, N)
     n_n = pl.cdiv(N, blk_n)
@@ -305,10 +314,10 @@ def hier_segment_sum_2d(x, w, onehot, *, blk_f: int = 512,
         grid=(n_f, n_n),
         in_specs=[
             pl.BlockSpec((blk_n, blk_f), lambda fi, ni: (ni, fi)),
-            pl.BlockSpec((blk_n,), lambda fi, ni: (ni,)),
+            pl.BlockSpec((1, blk_n), lambda fi, ni: (0, ni)),
             pl.BlockSpec((M, blk_n), lambda fi, ni: (0, ni)),
         ],
         out_specs=pl.BlockSpec((M, blk_f), lambda fi, ni: (0, fi)),
         out_shape=jax.ShapeDtypeStruct((M, F), jnp.float32),
         interpret=interpret,
-    )(x, w, onehot)
+    )(x, w[None, :], onehot)

@@ -23,8 +23,11 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-# ~16 MB of VMEM per TPU core; leave half for double-buffered pipelining.
-_AGG_VMEM_BUDGET = 8 * 2**20
+# Mosaic's default scoped VMEM limit is 16 MB.  The formula below counts
+# one copy of each block; the pipeline double-buffers the input and output
+# blocks, and the fp32 (HIGHEST) contraction keeps split copies of its
+# operands, so one counted copy may take about a quarter of the limit.
+_AGG_VMEM_BUDGET = 4 * 2**20
 
 
 def pick_agg_blk_f(num_rows: int, num_groups: int, f_local: int) -> int:
@@ -146,7 +149,7 @@ def hier_segment_aggregate(x, w, group_ids, *, num_groups: int,
     onehot = (gid[None, :] ==
               jnp.arange(num_groups, dtype=jnp.int32)[:, None]
               ).astype(jnp.float32)                       # (M, N)
-    gw = onehot @ w32                                     # (M,)
+    gw = jnp.dot(onehot, w32, precision=jax.lax.Precision.HIGHEST)  # (M,)
     x2 = x.reshape(N, -1)
     F = x2.shape[1]
     x2, _ = _pad_to(x2, 1, min(blk_f, max(F, 8)))

@@ -68,14 +68,22 @@ def hier_segment_aggregate_ref(x, w, group_ids, num_groups: int):
     (N, ...) where out[n] is the weighted mean of n's group.  Zero-member
     groups never appear in the output (no n maps to them).
     """
-    xf = x.reshape(x.shape[0], -1).astype(jnp.float32)
-    wf = w.astype(jnp.float32)
     gid = group_ids.astype(jnp.int32)
-    acc = jax.ops.segment_sum(wf[:, None] * xf, gid,
-                              num_segments=num_groups)
-    gw = jax.ops.segment_sum(wf, gid, num_segments=num_groups)
+    acc = hier_segment_sum_ref(x, w, gid, num_groups).reshape(num_groups, -1)
+    gw = jax.ops.segment_sum(w.astype(jnp.float32), gid,
+                             num_segments=num_groups)
     mean = acc / jnp.maximum(gw, 1e-12)[:, None]
     return mean[gid].reshape(x.shape)
+
+
+def hier_segment_sum_ref(x, w, group_ids, num_groups: int):
+    """Per-group weighted sums (eq. 6 numerator), fp32: (N, ...) ->
+    (num_groups, ...) with out[m] = sum_{n in group m} w[n] x[n]."""
+    xf = x.reshape(x.shape[0], -1).astype(jnp.float32)
+    acc = jax.ops.segment_sum(w.astype(jnp.float32)[:, None] * xf,
+                              group_ids.astype(jnp.int32),
+                              num_segments=num_groups)
+    return acc.reshape((num_groups,) + x.shape[1:])
 
 
 def decode_attention_ref(q, k_cache, v_cache, slot_pos, pos, *,

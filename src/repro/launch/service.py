@@ -1108,6 +1108,8 @@ def main(argv=None) -> dict:
     ap.add_argument("--trace", default=None, help="trace JSONL path")
     ap.add_argument("--verbose", action="store_true")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     fault_model = None
     fault_policy = None

@@ -19,6 +19,12 @@ deterministic RNG and then:
    constant per-edge merge mass, and bounded SLO degradation vs the
    fault-free baseline.
 
+The fault-free baseline runs as a subprocess too, and the exported
+trace is read back through the service's validating loader in a child:
+this process never imports JAX, so on a machine with an accelerator the
+children get the device (each in turn) and inherit the platform from
+the environment.
+
 Exit code 0 on success; any assertion failure is fatal (CI red).
 """
 from __future__ import annotations
@@ -39,8 +45,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "src"))
 
 from repro.checkpoint import latest_checkpoint, load_pytree  # noqa: E402
-from repro.launch.service import (  # noqa: E402
-    load_service_trace_jsonl)
 
 UES, EDGES, MAX_STALENESS = 16, 3, 3
 EVENTS = 100
@@ -52,14 +56,26 @@ SLO_FACTOR = 10.0           # smoke bound; bench_chaos holds the tight 2x
 TIMEOUT = 300.0
 
 
-def _cmd(ckpt_dir, scenario, fault_seed, *, resume=False, trace=None):
+# Reads a trace export through the service's validating loader; run in a
+# child so that this process stays off JAX.
+_LOAD_TRACE = """
+import json, sys
+from repro.launch.service import load_service_trace_jsonl
+print(json.dumps(load_service_trace_jsonl(sys.argv[1])))
+"""
+
+
+def _cmd(ckpt_dir, scenario=None, fault_seed=0, *, resume=False,
+         trace=None):
     cmd = [sys.executable, "-m", "repro.launch.service",
            "--ues", str(UES), "--edges", str(EDGES),
            "--max-staleness", str(MAX_STALENESS),
            "--segments", SEGMENTS, "--max-updates", str(EVENTS),
            "--ckpt-dir", ckpt_dir, "--ckpt-every", str(CKPT_EVERY),
-           "--keep-last-k", str(KEEP_LAST_K),
-           "--fault-scenario", scenario, "--fault-seed", str(fault_seed)]
+           "--keep-last-k", str(KEEP_LAST_K)]
+    if scenario:
+        cmd += ["--fault-scenario", scenario,
+                "--fault-seed", str(fault_seed)]
     if resume:
         cmd.append("--resume")
     if trace:
@@ -72,6 +88,15 @@ def _final_state(ckpt_dir):
     g = np.asarray(tree["g"], np.float32)
     trace = json.loads(str(np.asarray(tree["trace_json"])))
     return g, trace
+
+
+def _load_trace(path, env):
+    out = subprocess.run([sys.executable, "-c", _LOAD_TRACE, path],
+                         env=env, cwd=REPO, capture_output=True, text=True,
+                         timeout=TIMEOUT)
+    assert out.returncode == 0, f"trace export rejected: {out.stderr}"
+    header, records = json.loads(out.stdout.splitlines()[-1])
+    return header, records
 
 
 def _merges(trace):
@@ -150,7 +175,7 @@ def _run_schedule(i, env, baseline_p95):
             f"schedule {i}: no resume record"
 
         # the exported trace must pass the validating loader
-        header, records = load_service_trace_jsonl(trace_path)
+        header, records = _load_trace(trace_path, env)
         assert header["version"] == 2
 
         # per-edge merge mass is conserved (same cohort, every cycle)
@@ -186,20 +211,18 @@ def main(argv=None) -> None:
     ap.add_argument("--schedules", type=int, default=3)
     args = ap.parse_args(argv)
 
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
-               JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS", "cpu"))
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
 
-    # fault-free baseline for the SLO bound (in-process, cheap)
-    from repro.launch.service import (HFLService, Segment, ServiceConfig,
-                                      default_service_sim)
-    segs = tuple(Segment(n, float(l), float(d))
-                 for n, l, d in (p.split(":")
-                                 for p in SEGMENTS.split(",")))
-    base = HFLService(
-        default_service_sim(UES, EDGES, max_staleness=MAX_STALENESS),
-        ServiceConfig(segments=segs, max_staleness=MAX_STALENESS))
-    base.run(EVENTS)
-    baseline_p95 = base.summary()["p95"]
+    # fault-free baseline for the SLO bound (subprocess)
+    base_dir = tempfile.mkdtemp(prefix="chaos_base_")
+    try:
+        rc = subprocess.run(_cmd(base_dir), env=env, cwd=REPO,
+                            stdout=subprocess.DEVNULL,
+                            timeout=TIMEOUT).returncode
+        assert rc == 0, f"fault-free baseline run failed (rc={rc})"
+        baseline_p95 = _p95(_final_state(base_dir)[1])
+    finally:
+        shutil.rmtree(base_dir, ignore_errors=True)
     print(f"[chaos-smoke] fault-free baseline p95={baseline_p95:.3f}s")
 
     for i in range(args.schedules):

@@ -3,9 +3,9 @@ assert parity with an uninterrupted run.
 
     PYTHONPATH=src python tools/crash_smoke.py
 
-1. Runs the reference service IN-PROCESS to ``EVENTS`` cloud events
-   (no checkpointing) and keeps its final model + merge trace.
-2. Launches the same configuration as a SUBPROCESS
+1. Runs the reference service as a SUBPROCESS to ``EVENTS`` cloud events
+   and keeps its final checkpoint (model + merge trace).
+2. Launches the same configuration as another SUBPROCESS
    (``python -m repro.launch.service``) with durable checkpoints every
    ``CKPT_EVERY`` events, waits until at least two checkpoints exist,
    and ``kill -9``s it — an unclean death at an arbitrary point,
@@ -16,6 +16,10 @@ assert parity with an uninterrupted run.
 4. Compares the resumed run's FINAL checkpoint (the state at exactly
    ``EVENTS`` events, pre-drain) against the reference: the merge trace
    must match event-for-event and the published model to <= 1e-6.
+
+Every run is a child process and this one never imports JAX, so on a
+machine with an accelerator the children get the device (each in turn)
+and inherit the platform from the environment.
 
 Exit code 0 on success; any assertion failure is fatal (CI red).
 """
@@ -36,8 +40,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "src"))
 
 from repro.checkpoint import latest_checkpoint, load_pytree  # noqa: E402
-from repro.launch.service import (HFLService, Segment,  # noqa: E402
-                                  ServiceConfig, default_service_sim)
 
 UES, EDGES, MAX_STALENESS = 24, 4, 4
 EVENTS = 160
@@ -46,13 +48,6 @@ SEGMENTS = "iid_campus:1.0:40,iid_campus:4.0:60,iid_campus:1.0:inf"
 KILL_AFTER_CKPTS = 2
 TIMEOUT = 300.0
 
-
-def _segments():
-    out = []
-    for part in SEGMENTS.split(","):
-        name, load, dur = part.split(":")
-        out.append(Segment(name, float(load), float(dur)))
-    return tuple(out)
 
 
 def _service_cmd(ckpt_dir: str, resume: bool):
@@ -66,19 +61,28 @@ def _service_cmd(ckpt_dir: str, resume: bool):
     return cmd
 
 
+def _final_state(ckpt_dir: str):
+    """(published model, merge records) of the newest checkpoint."""
+    final = latest_checkpoint(ckpt_dir)
+    assert final is not None, f"no checkpoint in {ckpt_dir}"
+    tree, _meta = load_pytree(final)
+    trace = json.loads(str(np.asarray(tree["trace_json"])))
+    merges = [(round(r["t"], 9), r["edge"], r["cycle"])
+              for r in trace if r["kind"] == "merge"]
+    return np.asarray(tree["g"], np.float32), merges, trace
+
+
 def main() -> None:
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
-               JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS", "cpu"))
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    ref_dir = tempfile.mkdtemp(prefix="crash_smoke_ref_")
     tmp = tempfile.mkdtemp(prefix="crash_smoke_")
     try:
-        print(f"[crash-smoke] reference run ({EVENTS} events, in-process)")
-        ref = HFLService(
-            default_service_sim(UES, EDGES, max_staleness=MAX_STALENESS),
-            ServiceConfig(segments=_segments(),
-                          max_staleness=MAX_STALENESS))
-        ref.run(EVENTS)
-        ref_merges = [(round(r["t"], 9), r["edge"], r["cycle"])
-                      for r in ref.trace if r["kind"] == "merge"]
+        print(f"[crash-smoke] reference run ({EVENTS} events, subprocess)")
+        rc = subprocess.run(_service_cmd(ref_dir, resume=False), env=env,
+                            cwd=REPO, stdout=subprocess.DEVNULL,
+                            timeout=TIMEOUT).returncode
+        assert rc == 0, f"reference run failed (rc={rc})"
+        ref_g, ref_merges, _ = _final_state(ref_dir)
 
         print("[crash-smoke] victim subprocess + SIGKILL after "
               f"{KILL_AFTER_CKPTS} checkpoints")
@@ -116,13 +120,7 @@ def main() -> None:
                             cwd=REPO, timeout=TIMEOUT).returncode
         assert rc == 0, f"resume run failed (rc={rc})"
 
-        final = latest_checkpoint(tmp)
-        assert final is not None, "resume left no final checkpoint"
-        tree, _meta = load_pytree(final)
-        g = np.asarray(tree["g"], np.float32)
-        trace = json.loads(str(np.asarray(tree["trace_json"])))
-        merges = [(round(r["t"], 9), r["edge"], r["cycle"])
-                  for r in trace if r["kind"] == "merge"]
+        g, merges, trace = _final_state(tmp)
         resumes = sum(1 for r in trace if r["kind"] == "resume")
 
         assert resumes >= 1, "resumed run recorded no resume event"
@@ -132,12 +130,13 @@ def main() -> None:
         assert merges == ref_merges, (
             f"resumed merge trace diverged: {len(merges)} vs "
             f"{len(ref_merges)} records; first diff at {first_diff}")
-        err = float(np.abs(g - ref.g).max())
+        err = float(np.abs(g - ref_g).max())
         print(f"[crash-smoke] trace match ({len(merges)} merges), "
               f"model_err={err:.2e}")
         assert err <= 1e-6, f"final model diverged: {err}"
         print("[crash-smoke] OK")
     finally:
+        shutil.rmtree(ref_dir, ignore_errors=True)
         shutil.rmtree(tmp, ignore_errors=True)
 
 
