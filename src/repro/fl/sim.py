@@ -71,6 +71,25 @@ def _combine_masks(a: np.ndarray, b: np.ndarray) -> np.ndarray:
     return a[ai] & b[bi]
 
 
+def _vmapped(local_steps, batches):
+    """Every UE row's local steps at once, each on its own batch."""
+    return lambda p: jax.vmap(local_steps)(p, batches)
+
+
+def _edge_round(train, unravel, ravel, weights, group_ids, num_edges, mesh):
+    """Body of the b-iteration edge loop over the flat buffer: the local
+    steps (``train`` on the unravelled rows), then eq. 6 in one dispatch.
+    The ``hfl.*`` named scopes label the program's instructions (HLO
+    metadata only; see ``HFLSimulator.op_scopes``)."""
+    def edge_round(_, buf):
+        with jax.named_scope("hfl.local_step"):
+            trained = ravel(train(unravel(buf)))
+        with jax.named_scope("hfl.edge_agg"):
+            return aggregate.flat_edge_aggregate(
+                trained, weights, group_ids, num_edges, mesh=mesh)
+    return edge_round
+
+
 @dataclasses.dataclass
 class SimResult:
     times: np.ndarray          # (R,) cumulative simulated seconds per eval
@@ -80,6 +99,25 @@ class SimResult:
     schedule: HFLSchedule
     final_params: object
     timeline: object = None    # core.events.AsyncTimeline (async mode only)
+
+
+class _EvalLog:
+    """The evaluation points of one ``run``: clock, test accuracy and
+    loss, and weighted train loss, one entry each per point."""
+
+    def __init__(self):
+        self.times, self.accs, self.tlosses, self.trlosses = [], [], [], []
+
+    def last(self) -> str:
+        return f"acc={self.accs[-1]:.4f}  loss={self.tlosses[-1]:.4f}"
+
+    def result(self, schedule, final_params, timeline=None) -> SimResult:
+        return SimResult(times=np.array(self.times),
+                         test_acc=np.array(self.accs),
+                         test_loss=np.array(self.tlosses),
+                         train_loss=np.array(self.trlosses),
+                         schedule=schedule, final_params=final_params,
+                         timeline=timeline)
 
 
 class HFLSimulator:
@@ -253,6 +291,8 @@ class HFLSimulator:
         if mode == "async":
             self._depart_cycle, self._merge = self._build_async_ops()
         self._weighted_ops_cache = None
+        self.wave_rows_trained = 0       # hot rows the departure waves train
+        self.wave_rows_kept = 0          # ... and the rows they commit
         if fault_model is not None or sampler is not None:
             self._weighted_ops()    # build eagerly for fault/sampled runs
         # Weight-averaged train loss over ALL UEs (one vmap'd loss over the
@@ -302,19 +342,19 @@ class HFLSimulator:
             # unravel/ravel around local training are jit-fused reshapes,
             # and each aggregation event is a single dispatch (per-device
             # under shard_map when a mesh is threaded through).
-            def edge_round(_, buf):
-                p = unravel(buf)
+            def train(p):
                 if solver == "dane":
                     g_bar = clients.global_gradient(loss_fn, p, batches, weights)
-                    p = jax.vmap(lambda pp, bb: local_dane(pp, bb, g_bar))(
+                    return jax.vmap(lambda pp, bb: local_dane(pp, bb, g_bar))(
                         p, batches)
-                else:
-                    p = jax.vmap(local_gd)(p, batches)
-                return aggregate.flat_edge_aggregate(
-                    ravel(p), weights, group_ids, M, mesh=mesh)
+                return jax.vmap(local_gd)(p, batches)
 
-            flat = jax.lax.fori_loop(0, b, edge_round, flat)
-            return aggregate.flat_cloud_aggregate(flat, weights, mesh=mesh)
+            flat = jax.lax.fori_loop(
+                0, b, _edge_round(train, unravel, ravel, weights, group_ids,
+                                  M, mesh), flat)
+            with jax.named_scope("hfl.cloud_agg"):
+                return aggregate.flat_cloud_aggregate(flat, weights,
+                                                      mesh=mesh)
 
         # Donate the flat buffer so the cloud round updates it in place.
         return jax.jit(cloud_round, donate_argnums=0)
@@ -351,19 +391,18 @@ class HFLSimulator:
         local_gd = clients.gd_local_steps(loss_fn, a, lr)
 
         def depart_cycle(flat, g, batches, mask):
-            seeded = jnp.where(mask[:, None], g[None, :], flat)
-
-            def edge_round(_, buf):
-                p = jax.vmap(local_gd)(unravel(buf), batches)
-                return aggregate.flat_edge_aggregate(
-                    ravel(p), weights, group_ids, M, mesh=mesh)
-
-            new = jax.lax.fori_loop(0, b, edge_round, seeded)
-            return jnp.where(mask[:, None], new, flat)
+            with jax.named_scope("hfl.wave_select"):
+                seeded = jnp.where(mask[:, None], g[None, :], flat)
+            new = jax.lax.fori_loop(
+                0, b, _edge_round(_vmapped(local_gd, batches), unravel,
+                                  ravel, weights, group_ids, M, mesh), seeded)
+            with jax.named_scope("hfl.wave_select"):
+                return jnp.where(mask[:, None], new, flat)
 
         def merge(g, flat, eff_weights):
-            return aggregate.flat_staleness_merge(g, flat, eff_weights,
-                                                  w_total, mesh=mesh)
+            with jax.named_scope("hfl.merge"):
+                return aggregate.flat_staleness_merge(g, flat, eff_weights,
+                                                      w_total, mesh=mesh)
 
         return (jax.jit(depart_cycle, donate_argnums=0), jax.jit(merge))
 
@@ -401,24 +440,21 @@ class HFLSimulator:
         local_gd = clients.gd_local_steps(loss_fn, a, lr)
 
         def faulty_cloud_round(flat, batches, w_edge, w_cloud):
-            def edge_round(_, buf):
-                p = jax.vmap(local_gd)(unravel(buf), batches)
-                return aggregate.flat_edge_aggregate(
-                    ravel(p), w_edge, group_ids, M, mesh=mesh)
-
-            flat = jax.lax.fori_loop(0, b, edge_round, flat)
-            return aggregate.flat_cloud_aggregate(flat, w_cloud, mesh=mesh)
+            flat = jax.lax.fori_loop(
+                0, b, _edge_round(_vmapped(local_gd, batches), unravel,
+                                  ravel, w_edge, group_ids, M, mesh), flat)
+            with jax.named_scope("hfl.cloud_agg"):
+                return aggregate.flat_cloud_aggregate(flat, w_cloud,
+                                                      mesh=mesh)
 
         def faulty_depart(flat, g, batches, mask, w_edge):
-            seeded = jnp.where(mask[:, None], g[None, :], flat)
-
-            def edge_round(_, buf):
-                p = jax.vmap(local_gd)(unravel(buf), batches)
-                return aggregate.flat_edge_aggregate(
-                    ravel(p), w_edge, group_ids, M, mesh=mesh)
-
-            new = jax.lax.fori_loop(0, b, edge_round, seeded)
-            return jnp.where(mask[:, None], new, flat)
+            with jax.named_scope("hfl.wave_select"):
+                seeded = jnp.where(mask[:, None], g[None, :], flat)
+            new = jax.lax.fori_loop(
+                0, b, _edge_round(_vmapped(local_gd, batches), unravel,
+                                  ravel, w_edge, group_ids, M, mesh), seeded)
+            with jax.named_scope("hfl.wave_select"):
+                return jnp.where(mask[:, None], new, flat)
 
         return (jax.jit(faulty_cloud_round, donate_argnums=0),
                 jax.jit(faulty_depart, donate_argnums=0))
@@ -491,6 +527,37 @@ class HFLSimulator:
              self._faulty_depart) = self._weighted_ops_cache
         return self._weighted_ops_cache
 
+    def op_scopes(self) -> dict:
+        """``{program: {instruction: scope}}`` for every program this
+        simulator has built, keyed as the profiler names a program's runs
+        (``jit_cloud_round``, ``jit_depart_cycle``, ...): each program is
+        lowered with the live buffers and compiled, and its instructions
+        are mapped to their ``hfl.*`` named scope
+        (``roofline.hlo_cost.instruction_scopes``).  Instructions in no
+        scope are left out.  Compiles; call it outside any timed window."""
+        from repro.roofline.hlo_cost import instruction_scopes
+
+        n = int(self._hot_gids.shape[0])
+        flat, batches = self._flat, self._hot_batches
+        mask = jnp.zeros(n, bool)
+        g = self.place_cloud_vector(np.zeros(flat.shape[1], np.float32))
+        programs = [(self._cloud_round, (flat, batches))]
+        if self.mode == "async":
+            eff = jnp.asarray(np.zeros(n), jnp.float32)
+            programs += [(self._depart_cycle, (flat, g, batches, mask)),
+                         (self._merge, (g, flat, eff))]
+        if self._weighted_ops_cache is not None:
+            w_edge, w_cloud = self._fault_round_weights(np.ones(n, bool))
+            programs += [
+                (self._faulty_cloud_round, (flat, batches, w_edge, w_cloud)),
+                (self._faulty_depart, (flat, g, batches, mask, w_edge))]
+        out = {}
+        for fn, args in programs:
+            hlo = fn.lower(*args).compile().as_text()
+            name = hlo.split(None, 2)[1].rstrip(",")     # "HloModule <name>,"
+            out[name] = instruction_scopes(hlo)
+        return out
+
     # ------------------------------------------------------------------
     # Public replay hooks (mode='async') — the event-replay primitives
     # `_run_async` is built from, exposed so an external driver (the
@@ -527,9 +594,16 @@ class HFLSimulator:
         mean of the participants.  ``agg_weights`` overrides the base
         measure of that renormalization (per-cycle IPW weights from the
         service's sampler).
+
+        The wave program trains every hot row and commits only the masked
+        ones; ``wave_rows_trained`` and ``wave_rows_kept`` count both, on
+        the host from ``mask`` (a host array), over this simulator's life.
         """
         if self.mode != "async":
             raise RuntimeError("replay_departure requires mode='async'")
+        rows = np.asarray(mask, bool)
+        self.wave_rows_trained += rows.size
+        self.wave_rows_kept += int(rows.sum())
         if ue_ok is not None:
             w_edge, _ = self._fault_round_weights(np.asarray(ue_ok),
                                                   base=agg_weights)
@@ -601,6 +675,22 @@ class HFLSimulator:
 
     # ------------------------------------------------------------------
 
+    def _evaluate(self, log: _EvalLog, t: float, cloud_params,
+                  test_batch: dict) -> None:
+        """One evaluation point, inside the host span ``hfl.eval``: the
+        cloud model (``cloud_params()``) on the test batch and its
+        weighted train loss over every UE, read to the host in that
+        order (accuracy, test loss, train loss) and logged at clock
+        ``t``."""
+        with jax.profiler.TraceAnnotation("hfl.eval"):
+            gp = cloud_params()
+            loss, mets = self.loss_fn(gp, test_batch)
+            trl = self._train_loss(gp, self._hot_batches, self._hot_weights)
+            log.times.append(t)
+            log.accs.append(float(mets.get("acc", jnp.nan)))
+            log.tlosses.append(float(loss))
+            log.trlosses.append(float(trl))
+
     def run(self, test_batch: dict, rounds: Optional[int] = None,
             eval_every: int = 1, verbose: bool = False) -> SimResult:
         """Execute ``rounds`` cloud rounds (sync) or the equivalent async
@@ -625,28 +715,19 @@ class HFLSimulator:
             round_times = np.asarray(draws).max(axis=1)
         else:
             round_times = np.full(rounds, sched.cloud_round_time)  # eq. (34)
-        times, accs, tlosses, trlosses = [], [], [], []
+        log = _EvalLog()
         clock = 0.0
         test_batch = jax.tree.map(jnp.asarray, test_batch)
         for r in range(rounds):
-            self._flat = self._cloud_round(self._flat, self._hot_batches)
-            clock += float(round_times[r])
-            if (r + 1) % eval_every == 0 or r == rounds - 1:
-                gp = self.global_params()
-                loss, mets = self.loss_fn(gp, test_batch)
-                trl = self._train_loss(gp, self._hot_batches,
-                                       self._hot_weights)
-                times.append(clock)
-                accs.append(float(mets.get("acc", jnp.nan)))
-                tlosses.append(float(loss))
-                trlosses.append(float(trl))
-                if verbose:
-                    print(f"round {r+1:3d}/{rounds}  t={clock:9.2f}s  "
-                          f"acc={accs[-1]:.4f}  loss={tlosses[-1]:.4f}")
-        return SimResult(times=np.array(times), test_acc=np.array(accs),
-                         test_loss=np.array(tlosses),
-                         train_loss=np.array(trlosses),
-                         schedule=sched, final_params=self.global_params())
+            with jax.profiler.TraceAnnotation("hfl.round"):
+                self._flat = self._cloud_round(self._flat, self._hot_batches)
+                clock += float(round_times[r])
+                if (r + 1) % eval_every == 0 or r == rounds - 1:
+                    self._evaluate(log, clock, self.global_params, test_batch)
+                    if verbose:
+                        print(f"round {r+1:3d}/{rounds}  t={clock:9.2f}s  "
+                              f"{log.last()}")
+        return log.result(sched, self.global_params())
 
     def _run_sync_sampled(self, test_batch: dict, rounds: int,
                           eval_every: int, verbose: bool) -> SimResult:
@@ -679,31 +760,21 @@ class HFLSimulator:
             # have (full-fleet pacing — conservative).
             round_times = np.full(rounds, sched.cloud_round_time)
 
-        times, accs, tlosses, trlosses = [], [], [], []
+        log = _EvalLog()
         clock = 0.0
         test_batch = jax.tree.map(jnp.asarray, test_batch)
         for r in range(rounds):
-            w_edge, w_cloud = self._fault_round_weights(part_hot[r])
-            self._flat = self._faulty_cloud_round(
-                self._flat, self._hot_batches, w_edge, w_cloud)
-            clock += float(round_times[r])
-            if (r + 1) % eval_every == 0 or r == rounds - 1:
-                gp = self.global_params()
-                loss, mets = self.loss_fn(gp, test_batch)
-                trl = self._train_loss(gp, self._hot_batches,
-                                       self._hot_weights)
-                times.append(clock)
-                accs.append(float(mets.get("acc", jnp.nan)))
-                tlosses.append(float(loss))
-                trlosses.append(float(trl))
-                if verbose:
-                    print(f"round {r+1:3d}/{rounds}  t={clock:9.2f}s  "
-                          f"acc={accs[-1]:.4f}  loss={tlosses[-1]:.4f}  "
-                          f"cohort={int(part[r].sum())}")
-        return SimResult(times=np.array(times), test_acc=np.array(accs),
-                         test_loss=np.array(tlosses),
-                         train_loss=np.array(trlosses),
-                         schedule=sched, final_params=self.global_params())
+            with jax.profiler.TraceAnnotation("hfl.round"):
+                w_edge, w_cloud = self._fault_round_weights(part_hot[r])
+                self._flat = self._faulty_cloud_round(
+                    self._flat, self._hot_batches, w_edge, w_cloud)
+                clock += float(round_times[r])
+                if (r + 1) % eval_every == 0 or r == rounds - 1:
+                    self._evaluate(log, clock, self.global_params, test_batch)
+                    if verbose:
+                        print(f"round {r+1:3d}/{rounds}  t={clock:9.2f}s  "
+                              f"{log.last()}  cohort={int(part[r].sum())}")
+        return log.result(sched, self.global_params())
 
     def _run_sync_faulty(self, test_batch: dict, rounds: int,
                          eval_every: int, verbose: bool) -> SimResult:
@@ -742,35 +813,25 @@ class HFLSimulator:
                 self._participation_matrix(rounds))
         gids = np.asarray(self._hot_gids)
 
-        times, accs, tlosses, trlosses = [], [], [], []
+        log = _EvalLog()
         clock = 0.0
         test_batch = jax.tree.map(jnp.asarray, test_batch)
         for r in range(rounds):
-            ue_ok = surv[r] & ~down[r][gids]
-            if ue_ok.any():
-                w_edge, w_cloud = self._fault_round_weights(ue_ok)
-                self._flat = self._faulty_cloud_round(
-                    self._flat, self._hot_batches, w_edge, w_cloud)
-            # else: nothing delivered — the round is wasted wall-clock,
-            # the model stays put (no division by a zero weight mass).
-            clock += float(round_times[r])
-            if (r + 1) % eval_every == 0 or r == rounds - 1:
-                gp = self.global_params()
-                loss, mets = self.loss_fn(gp, test_batch)
-                trl = self._train_loss(gp, self._hot_batches,
-                                       self._hot_weights)
-                times.append(clock)
-                accs.append(float(mets.get("acc", jnp.nan)))
-                tlosses.append(float(loss))
-                trlosses.append(float(trl))
-                if verbose:
-                    print(f"round {r+1:3d}/{rounds}  t={clock:9.2f}s  "
-                          f"acc={accs[-1]:.4f}  loss={tlosses[-1]:.4f}  "
-                          f"survivors={int(ue_ok.sum())}")
-        return SimResult(times=np.array(times), test_acc=np.array(accs),
-                         test_loss=np.array(tlosses),
-                         train_loss=np.array(trlosses),
-                         schedule=sched, final_params=self.global_params())
+            with jax.profiler.TraceAnnotation("hfl.round"):
+                ue_ok = surv[r] & ~down[r][gids]
+                if ue_ok.any():
+                    w_edge, w_cloud = self._fault_round_weights(ue_ok)
+                    self._flat = self._faulty_cloud_round(
+                        self._flat, self._hot_batches, w_edge, w_cloud)
+                # else: nothing delivered — the round is wasted wall-clock,
+                # the model stays put (no division by a zero weight mass).
+                clock += float(round_times[r])
+                if (r + 1) % eval_every == 0 or r == rounds - 1:
+                    self._evaluate(log, clock, self.global_params, test_batch)
+                    if verbose:
+                        print(f"round {r+1:3d}/{rounds}  t={clock:9.2f}s  "
+                              f"{log.last()}  survivors={int(ue_ok.sum())}")
+        return log.result(sched, self.global_params())
 
     def _run_async(self, test_batch: dict, rounds: Optional[int],
                    eval_every: int, verbose: bool) -> SimResult:
@@ -834,7 +895,7 @@ class HFLSimulator:
         # wave's edge weights to them, merges zero out dead cohorts.
         pending_ok = np.ones(gids.shape[0], dtype=bool)
         last_cycle = np.zeros(sched.num_edges, dtype=np.int64)
-        times, accs, tlosses, trlosses = [], [], [], []
+        log = _EvalLog()
         updates_seen = 0
         for kind, ev in tl.trace:
             if kind == "depart":
@@ -869,22 +930,12 @@ class HFLSimulator:
             g = self.replay_merge(g, decay)
             updates_seen += 1
             if updates_seen % eval_every == 0 or updates_seen == num_updates:
-                gp = self.global_from_vector(g)
-                loss, mets = self.loss_fn(gp, test_batch)
-                trl = self._train_loss(gp, self._hot_batches,
-                                       self._hot_weights)
-                times.append(ev.t)
-                accs.append(float(mets.get("acc", jnp.nan)))
-                tlosses.append(float(loss))
-                trlosses.append(float(trl))
+                self._evaluate(log, ev.t, lambda: self.global_from_vector(g),
+                               test_batch)
                 if verbose:
                     print(f"update {updates_seen:4d}/{num_updates}  "
-                          f"t={ev.t:9.2f}s  acc={accs[-1]:.4f}  "
-                          f"loss={tlosses[-1]:.4f}")
+                          f"t={ev.t:9.2f}s  {log.last()}")
         # Leave the buffer consistent (all rows = cloud model) so
         # ``global_params``/repeated runs see the merged state.
         self._flat = jnp.zeros_like(self._flat) + g[None, :]
-        return SimResult(times=np.array(times), test_acc=np.array(accs),
-                         test_loss=np.array(tlosses),
-                         train_loss=np.array(trlosses), schedule=sched,
-                         final_params=self.global_params(), timeline=tl)
+        return log.result(sched, self.global_params(), timeline=tl)

@@ -535,12 +535,27 @@ class HFLService:
     def _replay_wave(self, departs: List[Tuple[int, float, int]]) -> None:
         """Train the departing cohorts from the published model: one
         ``replay_departure`` wave re-seeds their rows from ``g`` and runs
-        the b-iteration edge cycle in place.  With a configured sampler,
-        each cohort is cut to its cycle's sampled participants (composed
-        by AND with the degraded-mode shed mask; ONE ``survivor_weights``
-        renormalization downstream)."""
-        if not departs:
+        the b-iteration edge cycle in place.  The masks are composed
+        inside the host span ``hfl.masks``, the wave is placed and
+        dispatched inside ``hfl.wave``."""
+        with jax.profiler.TraceAnnotation("hfl.masks"):
+            masks = self._wave_masks(departs)
+        if masks is None:
             return
+        cohorts, ue_ok, agg_w = masks
+        with jax.profiler.TraceAnnotation("hfl.wave"):
+            g_dev = self.sim.place_cloud_vector(self.g)
+            self.sim.replay_departure(g_dev, cohorts, ue_ok=ue_ok,
+                                      agg_weights=agg_w)
+
+    def _wave_masks(self, departs: List[Tuple[int, float, int]]):
+        """``(cohorts, ue_ok, agg_weights)`` of the wave that trains the
+        departing cohorts, or None when no cohort trains.  With a
+        configured sampler, each cohort is cut to its cycle's sampled
+        participants (composed by AND with the degraded-mode shed mask;
+        ONE ``survivor_weights`` renormalization downstream)."""
+        if not departs:
+            return None
         gids = np.asarray(self.sim._hot_gids)
         fault_ok = None
         if self._fault_on:
@@ -564,7 +579,7 @@ class HFLService:
                     self._dead[key] = True
             departs = live
             if not departs:
-                return
+                return None
         cohorts = np.zeros(gids.shape[0], dtype=bool)
         for m_eng, _t, _c in departs:
             cohorts |= gids == int(self.active[m_eng])
@@ -603,9 +618,7 @@ class HFLService:
                             fallback = fault_ok[cohort]
                     combined[cohort] = fallback
             ue_ok = combined
-        g_dev = self.sim.place_cloud_vector(self.g)
-        self.sim.replay_departure(g_dev, cohorts, ue_ok=ue_ok,
-                                  agg_weights=agg_w)
+        return cohorts, ue_ok, agg_w
 
     # -- cloud merge queue ----------------------------------------------
 
@@ -630,15 +643,17 @@ class HFLService:
 
     def _drain(self, t: float) -> None:
         """Serve the FIFO queue up to simulated time ``t``: every job
-        whose ``merge_cost`` service completes by ``t`` publishes."""
-        while self.queue:
-            start = max(self.queue[0].t_arr, self.busy_until)
-            finish = start + self.merge_cost
-            if finish > t:
-                break
-            job = self.queue.pop(0)
-            self.busy_until = finish
-            self._apply(job, finish)
+        whose ``merge_cost`` service completes by ``t`` publishes (host
+        span ``hfl.publish``)."""
+        with jax.profiler.TraceAnnotation("hfl.publish"):
+            while self.queue:
+                start = max(self.queue[0].t_arr, self.busy_until)
+                finish = start + self.merge_cost
+                if finish > t:
+                    break
+                job = self.queue.pop(0)
+                self.busy_until = finish
+                self._apply(job, finish)
 
     def _shed_excess(self, t: float) -> None:
         """Degraded-mode backlog cut: drop the lowest-(mass, arrival,
@@ -738,20 +753,24 @@ class HFLService:
         rows fold through the persistent streaming accumulator chunk by
         chunk instead — O(chunk * F) resident regardless of cohort size,
         bitwise-stable across resumes, parity <= 1e-5 with the direct
-        read."""
-        chunk = self.config.merge_stream_chunk
-        if chunk <= 0:
-            return np.asarray(
-                jax.device_get(self.sim.edge_mean_row(m_full)), np.float32)
-        gids = np.asarray(self.sim._hot_gids)
-        w = np.asarray(self.sim._hot_weights, np.float64)
-        idx = np.flatnonzero(gids == int(m_full))
-        acc = self._stream_acc.reset()
-        for s in range(0, idx.size, chunk):
-            sel = idx[s:s + chunk]
-            acc.add(self.sim.hot_rows(sel), w[sel],
-                    np.zeros(sel.size, np.int32))
-        return np.asarray(jax.device_get(acc.edge_means()[0]), np.float32)
+        read.  Host span ``hfl.merge_row``: the pull waits for the wave
+        that wrote the row."""
+        with jax.profiler.TraceAnnotation("hfl.merge_row"):
+            chunk = self.config.merge_stream_chunk
+            if chunk <= 0:
+                return np.asarray(
+                    jax.device_get(self.sim.edge_mean_row(m_full)),
+                    np.float32)
+            gids = np.asarray(self.sim._hot_gids)
+            w = np.asarray(self.sim._hot_weights, np.float64)
+            idx = np.flatnonzero(gids == int(m_full))
+            acc = self._stream_acc.reset()
+            for s in range(0, idx.size, chunk):
+                sel = idx[s:s + chunk]
+                acc.add(self.sim.hot_rows(sel), w[sel],
+                        np.zeros(sel.size, np.int32))
+            return np.asarray(jax.device_get(acc.edge_means()[0]),
+                              np.float32)
 
     def _announce_segments(self) -> None:
         """Emit one ``failover`` trace record the first time the clock
@@ -772,12 +791,19 @@ class HFLService:
     def run(self, max_updates: int, verbose: bool = False) -> dict:
         """Process engine events until ``events_done`` reaches
         ``max_updates`` (cumulative across resumes), checkpointing every
-        ``ckpt_every`` events.  Returns ``summary()``."""
+        ``ckpt_every`` events.  Returns ``summary()``.
+
+        Each engine step and its processing run inside the host span
+        ``hfl.update`` (the step itself inside ``hfl.engine_step``); the
+        spans record only while a ``jax.profiler`` trace runs."""
         cfg = self.config
         wall0 = time.perf_counter()
         try:
             while self.events_done < max_updates:
-                self._process(self.engine.step())
+                with jax.profiler.TraceAnnotation("hfl.update"):
+                    with jax.profiler.TraceAnnotation("hfl.engine_step"):
+                        records = self.engine.step()
+                    self._process(records)
                 if (cfg.ckpt_every and cfg.ckpt_dir and
                         self.events_done % cfg.ckpt_every == 0):
                     self.checkpoint()
@@ -826,6 +852,8 @@ class HFLService:
                                 if self.run_wall > 0 else 0.0),
             updates_per_wall_sec=(self.events_done / self.run_wall
                                   if self.run_wall > 0 else 0.0),
+            wave_rows_trained=self.sim.wave_rows_trained,
+            wave_rows_kept=self.sim.wave_rows_kept,
         )
 
     def global_params(self):
@@ -900,20 +928,27 @@ class HFLService:
 
     def checkpoint(self) -> str:
         """Atomically persist the full control-plane state as
-        ``ckpt-<n>.npz`` under ``config.ckpt_dir``."""
+        ``ckpt-<n>.npz`` under ``config.ckpt_dir``.  Host spans:
+        ``hfl.checkpoint`` around ``hfl.ckpt_state`` (gathering the state,
+        the flat buffer's device pull and the trace's JSON dump included)
+        and ``hfl.ckpt_write`` (the save and the GC)."""
         if not self.config.ckpt_dir:
             raise ValueError("config.ckpt_dir is unset")
         t0 = time.perf_counter()
-        self._ckpt_count += 1
-        path = f"{self.config.ckpt_dir}/ckpt-{self._ckpt_count}.npz"
-        out = save_pytree(path, self._state_tree(), metadata={
-            "schema": SERVICE_CKPT_VERSION,
-            "config": self.config.to_json(),
-        })
-        gc_n = 0
-        if self.config.keep_last_k > 0:
-            gc_n = len(gc_checkpoints(self.config.ckpt_dir,
-                                      self.config.keep_last_k))
+        with jax.profiler.TraceAnnotation("hfl.checkpoint"):
+            self._ckpt_count += 1
+            path = f"{self.config.ckpt_dir}/ckpt-{self._ckpt_count}.npz"
+            with jax.profiler.TraceAnnotation("hfl.ckpt_state"):
+                tree = self._state_tree()
+            with jax.profiler.TraceAnnotation("hfl.ckpt_write"):
+                out = save_pytree(path, tree, metadata={
+                    "schema": SERVICE_CKPT_VERSION,
+                    "config": self.config.to_json(),
+                })
+                gc_n = 0
+                if self.config.keep_last_k > 0:
+                    gc_n = len(gc_checkpoints(self.config.ckpt_dir,
+                                              self.config.keep_last_k))
         dt = time.perf_counter() - t0
         self.ckpt_wall += dt
         self.trace.append(dict(kind="ckpt", t=self.clock,

@@ -333,3 +333,30 @@ def analyze(hlo: str) -> Dict[str, float]:
     for k, v in total.coll.items():
         out[f"coll_{k}"] = v
     return out
+
+
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def instruction_scopes(hlo: str, prefix: str = "hfl.") -> Dict[str, str]:
+    """``{instruction name: scope}`` for every instruction of a compiled
+    module whose ``metadata={op_name=...}`` path holds a component that
+    starts with ``prefix`` (a ``jax.named_scope``); the outermost such
+    component is the scope.  Instructions outside every such scope are
+    left out.
+
+    XLA gives a fusion the metadata of its root, so a fusion that crosses
+    a scope boundary takes its root's scope; instructions of while bodies
+    and custom calls keep the scope they were traced in."""
+    comps, _ = parse_module(hlo)
+    out: Dict[str, str] = {}
+    for comp in comps.values():
+        for op in comp.ops:
+            m = _OP_NAME.search(op.rest)
+            if m is None:
+                continue
+            scope = next((c for c in m.group(1).split("/")
+                          if c.startswith(prefix)), None)
+            if scope is not None:
+                out[op.name] = scope
+    return out
