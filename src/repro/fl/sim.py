@@ -30,10 +30,12 @@ is dropped.  ``repro.core.events`` simulates each edge's cycle
 ``b * tau_m + t_mc`` on its own clock with SSP staleness gating
 (``max_staleness`` cycles of lead, 0 = exact synchronous barrier), and the
 run REPLAYS that event trace: departures re-seed the departing edges' rows
-from the cloud model and run their b-iteration cycle in place
-(``flat_edge_aggregate`` on the same flat/sharded buffer), arrivals merge
-into the cloud vector with weights decayed by ``staleness_decay **
-version_lag`` (``flat_staleness_merge`` — one psum under a mesh).  At
+from the cloud model and run their b-iteration cycle on those rows alone,
+gathered into a row bucket of a few static sizes, then written back
+(``flat_edge_aggregate``; the whole buffer at N_hot rows or under a
+mesh), arrivals merge into the cloud vector with weights decayed by
+``staleness_decay ** version_lag`` (``flat_staleness_merge`` — one psum
+under a mesh).  At
 ``max_staleness=0`` the trajectory reproduces the synchronous path to
 float tolerance; with a bound > 0 fast edges re-enter immediately and the
 makespan drops strictly below the eq. 34 bound on heterogeneous fleets.
@@ -88,6 +90,52 @@ def _edge_round(train, unravel, ravel, weights, group_ids, num_edges, mesh):
             return aggregate.flat_edge_aggregate(
                 trained, weights, group_ids, num_edges, mesh=mesh)
     return edge_round
+
+
+def _wave_ladder(group_ids: np.ndarray) -> tuple:
+    """Row-bucket sizes of a departure wave, ascending: W, 2W and 4W rows,
+    W the largest cohort, each rounded up to a whole 8-row sublane tile and
+    kept only below N_hot, then N_hot itself (the whole buffer)."""
+    n = int(group_ids.shape[0])
+    w = int(np.bincount(group_ids).max())
+    sizes = {-(-k * w // 8) * 8 for k in (1, 2, 4)}
+    return tuple(sorted(s for s in sizes if s < n)) + (n,)
+
+
+def _departure_wave(local_steps, unravel, ravel, b, group_ids, num_edges,
+                    mesh):
+    """Body of a departure wave, ``wave(flat, g, batches, rows, weights)``.
+
+    ``rows`` is either the (N_hot,) bool mask of the departing rows (the
+    N_hot bucket: every row trains, only the masked ones are committed)
+    or an int32 (bucket,) index of them, padded with N_hot.  An index
+    gathers only its rows' batches, weights and group ids, seeds every
+    row from ``g``, runs the b-iteration edge cycle on the bucket and
+    scatters the real rows back; padding rows carry weight 0 and the
+    group id of a departing row, and the scatter drops them."""
+    def cycle(seeded, batches, weights, gids):
+        return jax.lax.fori_loop(
+            0, b, _edge_round(_vmapped(local_steps, batches), unravel, ravel,
+                              weights, gids, num_edges, mesh), seeded)
+
+    def wave(flat, g, batches, rows, weights):
+        if rows.dtype == jnp.bool_:
+            with jax.named_scope("hfl.wave_select"):
+                seeded = jnp.where(rows[:, None], g[None, :], flat)
+            new = cycle(seeded, batches, weights, group_ids)
+            with jax.named_scope("hfl.wave_select"):
+                return jnp.where(rows[:, None], new, flat)
+        n = flat.shape[0]
+        with jax.named_scope("hfl.wave_select"):
+            real = rows < n
+            at = jnp.where(real, rows, jnp.minimum(rows[0], n - 1))
+            seeded = jnp.broadcast_to(g[None, :], (rows.shape[0],) + g.shape)
+            sub = jax.tree.map(lambda x: x[at], batches)
+        new = cycle(seeded, sub, jnp.where(real, weights[at], 0.0),
+                    group_ids[at])
+        with jax.named_scope("hfl.wave_select"):
+            return flat.at[rows].set(new, mode="drop")
+    return wave
 
 
 @dataclasses.dataclass
@@ -291,8 +339,15 @@ class HFLSimulator:
         if mode == "async":
             self._depart_cycle, self._merge = self._build_async_ops()
         self._weighted_ops_cache = None
-        self.wave_rows_trained = 0       # hot rows the departure waves train
+        # Row buckets of the departure waves; a mesh keeps the whole
+        # buffer (a gather across its row shards would add collectives).
+        self._wave_ladder = (
+            (int(self._hot_gids.shape[0]),) if mesh is not None
+            else _wave_ladder(np.asarray(self._hot_gids)))
+        self._wave_exec = {}             # twin -> {bucket: compiled wave}
+        self.wave_rows_trained = 0       # bucket rows the waves train
         self.wave_rows_kept = 0          # ... and the rows they commit
+        self.wave_bucket_runs = {}       # bucket rows -> waves
         if fault_model is not None or sampler is not None:
             self._weighted_ops()    # build eagerly for fault/sampled runs
         # Weight-averaged train loss over ALL UEs (one vmap'd loss over the
@@ -328,11 +383,7 @@ class HFLSimulator:
         solver = self.solver
         dane_mu = self.dane_mu
         mesh = self.mesh
-        if self._slayout is not None:
-            unravel, ravel = (self._slayout.unravel_padded,
-                              self._slayout.ravel_padded)
-        else:
-            unravel, ravel = self._layout.unravel, self._layout.ravel
+        unravel, ravel = self._row_codec()
 
         local_gd = clients.gd_local_steps(loss_fn, a, lr)
         local_dane = clients.dane_local_steps(loss_fn, a, lr, mu_prox=dane_mu)
@@ -362,42 +413,33 @@ class HFLSimulator:
     def _build_async_ops(self):
         """Jitted bodies of the async event replay (mode='async').
 
-        * ``depart_cycle(flat, g, batches, mask)`` — re-seed the departing
-          edges' rows (``mask``) from the cloud vector ``g``, run their
-          full b-iteration edge cycle (Alg. 1 lines 4-9: a local GD steps
-          + eq. 6 edge aggregation, b times) and commit ONLY the masked
-          rows; mid-flight edges' rows pass through untouched.  One
-          dispatch per departure wave, compiled once.  Host-compute cost:
-          the wave trains the WHOLE buffer and discards unmasked rows (a
-          runtime mask keeps one compilation for every wave shape), so an
-          async run costs up to M_active x the sync path's training FLOPs
-          for the same delivery quota — the SIMULATED clock is unaffected,
-          and at max_staleness=0 waves contain all edges, so the barrier
-          replay costs the same as sync.
+        * ``depart_cycle(flat, g, batches, rows)`` — one departure wave
+          (``_departure_wave``): re-seed the departing edges' rows from
+          the cloud vector ``g``, run their full b-iteration edge cycle
+          (Alg. 1 lines 4-9: a local GD steps + eq. 6 edge aggregation, b
+          times) and commit them; other rows pass through untouched.  One
+          dispatch per wave.  ``rows`` picks the program: an int32 index
+          of a few static bucket sizes (``_wave_ladder``) trains only the
+          gathered rows, so a wave of one edge costs about one edge's
+          share of the sync path's training FLOPs; the (N_hot,) bool mask
+          of the N_hot bucket trains the whole buffer and commits the
+          masked rows — every wave at max_staleness=0 (all edges) and
+          every wave under a mesh, at the sync path's cost.
         * ``merge(g, flat, eff_weights)`` — staleness-weighted cloud merge
           (``flat_staleness_merge``; reduces to eq. 10 at the barrier).
         """
         a, b = self.schedule.a, self.schedule.b
         M = self.schedule.num_edges
-        loss_fn, lr = self.loss_fn, self.lr
-        weights, group_ids = self._hot_weights, self._hot_gids
+        weights = self._hot_weights
         mesh = self.mesh
         w_total = float(jnp.sum(self._hot_weights))
-        if self._slayout is not None:
-            unravel, ravel = (self._slayout.unravel_padded,
-                              self._slayout.ravel_padded)
-        else:
-            unravel, ravel = self._layout.unravel, self._layout.ravel
-        local_gd = clients.gd_local_steps(loss_fn, a, lr)
+        unravel, ravel = self._row_codec()
+        wave = _departure_wave(clients.gd_local_steps(self.loss_fn, a,
+                                                      self.lr),
+                               unravel, ravel, b, self._hot_gids, M, mesh)
 
-        def depart_cycle(flat, g, batches, mask):
-            with jax.named_scope("hfl.wave_select"):
-                seeded = jnp.where(mask[:, None], g[None, :], flat)
-            new = jax.lax.fori_loop(
-                0, b, _edge_round(_vmapped(local_gd, batches), unravel,
-                                  ravel, weights, group_ids, M, mesh), seeded)
-            with jax.named_scope("hfl.wave_select"):
-                return jnp.where(mask[:, None], new, flat)
+        def depart_cycle(flat, g, batches, rows):
+            return wave(flat, g, batches, rows, weights)
 
         def merge(g, flat, eff_weights):
             with jax.named_scope("hfl.merge"):
@@ -419,25 +461,22 @@ class HFLSimulator:
           the edges that actually delivered (a dead cohort's zero rows
           carry zero cloud weight — the global model stays the unbiased
           mean of survivors).
-        * ``faulty_depart(flat, g, batches, mask, w_edge)`` — the async
-          departure wave with the wave's survivor weights; non-departing
-          groups' weights are irrelevant (their rows are discarded by
-          ``mask``).
+        * ``faulty_depart(flat, g, batches, rows, w_edge)`` — the async
+          departure wave (``depart_cycle``'s row buckets) with the wave's
+          survivor weights; non-departing groups' weights are irrelevant
+          (their rows are neither gathered nor committed).
 
         Both take the weights as RUNTIME arguments: one compilation
         serves every fault pattern.
         """
         a, b = self.schedule.a, self.schedule.b
         M = self.schedule.num_edges
-        loss_fn, lr = self.loss_fn, self.lr
         group_ids = self._hot_gids
         mesh = self.mesh
-        if self._slayout is not None:
-            unravel, ravel = (self._slayout.unravel_padded,
-                              self._slayout.ravel_padded)
-        else:
-            unravel, ravel = self._layout.unravel, self._layout.ravel
-        local_gd = clients.gd_local_steps(loss_fn, a, lr)
+        unravel, ravel = self._row_codec()
+        local_gd = clients.gd_local_steps(self.loss_fn, a, self.lr)
+        wave = _departure_wave(local_gd, unravel, ravel, b, group_ids, M,
+                               mesh)
 
         def faulty_cloud_round(flat, batches, w_edge, w_cloud):
             flat = jax.lax.fori_loop(
@@ -447,17 +486,17 @@ class HFLSimulator:
                 return aggregate.flat_cloud_aggregate(flat, w_cloud,
                                                       mesh=mesh)
 
-        def faulty_depart(flat, g, batches, mask, w_edge):
-            with jax.named_scope("hfl.wave_select"):
-                seeded = jnp.where(mask[:, None], g[None, :], flat)
-            new = jax.lax.fori_loop(
-                0, b, _edge_round(_vmapped(local_gd, batches), unravel,
-                                  ravel, w_edge, group_ids, M, mesh), seeded)
-            with jax.named_scope("hfl.wave_select"):
-                return jnp.where(mask[:, None], new, flat)
+        def faulty_depart(flat, g, batches, rows, w_edge):
+            return wave(flat, g, batches, rows, w_edge)
 
         return (jax.jit(faulty_cloud_round, donate_argnums=0),
                 jax.jit(faulty_depart, donate_argnums=0))
+
+    def _row_codec(self):
+        """``(unravel, ravel)`` between the flat rows and stacked pytrees."""
+        if self._slayout is not None:
+            return self._slayout.unravel_padded, self._slayout.ravel_padded
+        return self._layout.unravel, self._layout.ravel
 
     def _fault_survivor_matrix(self, fc):
         """``fc.survivors`` mapped onto the HOT row layout."""
@@ -533,30 +572,60 @@ class HFLSimulator:
         (``jit_cloud_round``, ``jit_depart_cycle``, ...): each program is
         lowered with the live buffers and compiled, and its instructions
         are mapped to their ``hfl.*`` named scope
-        (``roofline.hlo_cost.instruction_scopes``).  Instructions in no
-        scope are left out.  Compiles; call it outside any timed window."""
+        (``roofline.hlo_cost.instruction_scopes``).  A wave twin's map is
+        the union over the programs of its bucket ladder, which share its
+        name.  Instructions in no scope are left out.  Compiles; call it
+        outside any timed window."""
         from repro.roofline.hlo_cost import instruction_scopes
 
         n = int(self._hot_gids.shape[0])
         flat, batches = self._flat, self._hot_batches
-        mask = jnp.zeros(n, bool)
         g = self.place_cloud_vector(np.zeros(flat.shape[1], np.float32))
         programs = [(self._cloud_round, (flat, batches))]
         if self.mode == "async":
             eff = jnp.asarray(np.zeros(n), jnp.float32)
-            programs += [(self._depart_cycle, (flat, g, batches, mask)),
-                         (self._merge, (g, flat, eff))]
+            programs.append((self._merge, (g, flat, eff)))
         if self._weighted_ops_cache is not None:
             w_edge, w_cloud = self._fault_round_weights(np.ones(n, bool))
-            programs += [
-                (self._faulty_cloud_round, (flat, batches, w_edge, w_cloud)),
-                (self._faulty_depart, (flat, g, batches, mask, w_edge))]
+            programs.append((self._faulty_cloud_round,
+                             (flat, batches, w_edge, w_cloud)))
+        compiled = [fn.lower(*args).compile() for fn, args in programs]
+        twins = ([self._depart_cycle] if self.mode == "async" else []) + (
+            [self._faulty_depart] if self._weighted_ops_cache else [])
+        for twin in twins:
+            compiled += self._wave_programs(twin).values()
         out = {}
-        for fn, args in programs:
-            hlo = fn.lower(*args).compile().as_text()
+        for prog in compiled:
+            hlo = prog.as_text()
             name = hlo.split(None, 2)[1].rstrip(",")     # "HloModule <name>,"
-            out[name] = instruction_scopes(hlo)
+            out.setdefault(name, {}).update(instruction_scopes(hlo))
         return out
+
+    def _wave_programs(self, twin) -> dict:
+        """``{bucket rows: program}`` of the wave twin ``twin``.  The
+        twin's first call compiles every bucket of the ladder, so no later
+        wave compiles; the N_hot bucket is the twin with the (N_hot,) bool
+        mask.  Under a mesh the one bucket is the jitted twin itself."""
+        progs = self._wave_exec.get(twin)
+        if progs is not None:
+            return progs
+        n, f = self._flat.shape
+        if self.mesh is not None:
+            progs = {n: twin}
+        else:
+            def spec(x):
+                return jax.ShapeDtypeStruct(x.shape, x.dtype)
+            head = (spec(self._flat), jax.ShapeDtypeStruct((f,), jnp.float32),
+                    jax.tree.map(spec, self._hot_batches))
+            tail = (() if twin is self._depart_cycle
+                    else (jax.ShapeDtypeStruct((n,), jnp.float32),))
+            progs = {}
+            for size in self._wave_ladder:
+                rows = (jax.ShapeDtypeStruct((n,), jnp.bool_) if size == n
+                        else jax.ShapeDtypeStruct((size,), jnp.int32))
+                progs[size] = twin.lower(*head, rows, *tail).compile()
+        self._wave_exec[twin] = progs
+        return progs
 
     # ------------------------------------------------------------------
     # Public replay hooks (mode='async') — the event-replay primitives
@@ -595,25 +664,41 @@ class HFLSimulator:
         measure of that renormalization (per-cycle IPW weights from the
         service's sampler).
 
-        The wave program trains every hot row and commits only the masked
-        ones; ``wave_rows_trained`` and ``wave_rows_kept`` count both, on
-        the host from ``mask`` (a host array), over this simulator's life.
+        The wave trains the smallest bucket of ``_wave_ladder`` that holds
+        the masked rows, gathered; the N_hot bucket (a full-fleet wave, a
+        mask that is not a union of whole cohorts, or any mesh run) trains
+        every row and commits the masked ones.  ``wave_rows_trained``
+        counts the bucket rows, ``wave_rows_kept`` the committed rows and
+        ``wave_bucket_runs`` the waves per bucket, over this simulator's
+        life.
         """
         if self.mode != "async":
             raise RuntimeError("replay_departure requires mode='async'")
-        rows = np.asarray(mask, bool)
-        self.wave_rows_trained += rows.size
-        self.wave_rows_kept += int(rows.sum())
+        mask = np.asarray(mask, bool)
+        idx = np.flatnonzero(mask)
+        n = mask.size
+        gids = np.asarray(self._hot_gids)
+        whole = idx.size == np.isin(gids, gids[idx]).sum()
+        bucket = next(s for s in self._wave_ladder
+                      if s >= idx.size and (whole or s == n))
+        self.wave_rows_trained += bucket
+        self.wave_rows_kept += idx.size
+        self.wave_bucket_runs[bucket] = self.wave_bucket_runs.get(bucket,
+                                                                  0) + 1
+        if bucket == n:
+            rows = jnp.asarray(mask)
+        else:
+            rows = np.full(bucket, n, np.int32)
+            rows[:idx.size] = idx
         if ue_ok is not None:
             w_edge, _ = self._fault_round_weights(np.asarray(ue_ok),
                                                   base=agg_weights)
-            _, faulty_depart = self._weighted_ops()
-            self._flat = faulty_depart(self._flat, g, self._hot_batches,
-                                       jnp.asarray(mask), w_edge)
+            _, twin = self._weighted_ops()
+            self._flat = self._wave_programs(twin)[bucket](
+                self._flat, g, self._hot_batches, rows, w_edge)
         else:
-            self._flat = self._depart_cycle(self._flat, g,
-                                            self._hot_batches,
-                                            jnp.asarray(mask))
+            self._flat = self._wave_programs(self._depart_cycle)[bucket](
+                self._flat, g, self._hot_batches, rows)
 
     def replay_merge(self, g, decay: np.ndarray):
         """Staleness-weighted cloud merge of the arrived edges.

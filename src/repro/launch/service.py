@@ -854,6 +854,7 @@ class HFLService:
                                   if self.run_wall > 0 else 0.0),
             wave_rows_trained=self.sim.wave_rows_trained,
             wave_rows_kept=self.sim.wave_rows_kept,
+            wave_bucket_runs=dict(self.sim.wave_bucket_runs),
         )
 
     def global_params(self):

@@ -1,6 +1,7 @@
 """Async simulator mode: sync-trajectory parity at max_staleness=0,
 staleness-bounded progress, determinism, and argument validation."""
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -177,3 +178,168 @@ def test_async_requires_problem_for_cycle_times(async_setup):
     sim = HFLSimulator(bare, _loss_fn, init, ue_data, mode="async")
     with pytest.raises(ValueError):
         sim.run(test, rounds=1)
+
+
+# ---------------------------------------------------------------------------
+# Departure waves in row buckets: a wave gathers the departing cohorts'
+# rows into the smallest bucket of the ladder that holds them.
+# ---------------------------------------------------------------------------
+
+#: Cohort sizes of the bucket fleet: W = 8, ladder (8, 16, 32, 40).
+COHORTS = (8, 8, 8, 8, 5, 3)
+LADDER = (8, 16, 32, 40)
+#: wave -> (departing edges, bucket it takes)
+WAVES = {
+    "empty": ((), 8),
+    "one_padded": ((4,), 8),
+    "one_exact": ((0,), 8),
+    "two_exact": ((0, 1), 16),
+    "three_exact": ((0, 4, 5), 16),
+    "three_padded": ((0, 1, 4), 32),
+    "four_exact": ((0, 1, 2, 3), 32),
+    "full_fleet": ((0, 1, 2, 3, 4, 5), 40),
+}
+
+
+def _bucket_sim(mesh=None):
+    """An async simulator over 40 UEs whose cohorts are COHORTS, their
+    rows interleaved, with every row holding its own state."""
+    import dataclasses
+    m = len(COHORTS)
+    prob = HFLProblem(num_edges=m, num_ues=sum(COHORTS), epsilon=0.25,
+                      seed=0, samples_lo=20, samples_hi=60)
+    sch = schedule.plan(prob)
+    gids = np.random.default_rng(1).permutation(
+        np.repeat(np.arange(m), COHORTS))
+    sch = dataclasses.replace(sch, a=3, b=2,
+                              assoc=np.eye(m, dtype=sch.assoc.dtype)[gids])
+    train = synthetic.logreg_data(seed=0, n=sum(COHORTS) * 12, dim=12,
+                                  num_classes=4)
+    ue_data = [{k: v[12 * i:12 * (i + 1)] for k, v in train.items()}
+               for i in range(sum(COHORTS))]
+    init = lenet.logreg_init(jax.random.PRNGKey(0), 12, 4)
+    sim = HFLSimulator(sch, _loss_fn, init, ue_data, lr=0.05, mode="async",
+                       max_staleness=2, mesh=mesh)
+    rng = np.random.default_rng(2)
+    sim.set_flat_state(rng.normal(size=sim._flat.shape).astype(np.float32))
+    g = sim.place_cloud_vector(
+        rng.normal(size=sim._flat.shape[1]).astype(np.float32))
+    return sim, g, gids
+
+
+def _wave_mask(gids, edges):
+    return np.isin(gids, edges)
+
+
+def _shed(gids, mask):
+    """Drop every third departing row, keeping one per cohort."""
+    ue_ok = np.ones(gids.size, bool)
+    rows = np.flatnonzero(mask)
+    ue_ok[rows[1::3]] = False
+    for m in np.unique(gids[rows]):
+        ue_ok[rows[gids[rows] == m][0]] = True
+    return ue_ok
+
+
+@pytest.fixture(scope="module", params=["jnp", "pallas"])
+def bucket_sim(request):
+    """The bucket fleet on the jnp aggregation and on the Pallas kernels
+    (interpret mode off the chip), both wave twins built."""
+    from repro.fl import aggregate
+    orig = aggregate._select_kernel
+    if request.param == "pallas":
+        aggregate._select_kernel = lambda use_kernel: True
+    try:
+        sim, g, gids = _bucket_sim()
+        sim._weighted_ops()
+        sim._wave_programs(sim._depart_cycle)
+        sim._wave_programs(sim._faulty_depart)
+        yield sim, g, gids
+    finally:
+        aggregate._select_kernel = orig
+
+
+@pytest.mark.parametrize("twin", ["depart_cycle", "faulty_depart"])
+@pytest.mark.parametrize("wave", list(WAVES))
+def test_bucketed_wave_matches_the_full_buffer_wave(bucket_sim, wave, twin):
+    """A gathered wave commits what the full-buffer program commits (to
+    1e-6 relative), leaves every other row bit-identical and puts no NaN
+    in any row; the full-fleet wave is the full-buffer program itself."""
+    sim, g, gids = bucket_sim
+    edges, bucket = WAVES[wave]
+    mask = _wave_mask(gids, edges)
+    ue_ok = _shed(gids, mask) if twin == "faulty_depart" else None
+    before = sim.flat_state()
+    runs = dict(sim.wave_bucket_runs)
+    sim.replay_departure(g, mask, ue_ok=ue_ok)
+    got = sim.flat_state()
+    assert sim.wave_bucket_runs[bucket] == runs.get(bucket, 0) + 1
+
+    sim.set_flat_state(before)
+    if ue_ok is None:
+        flat = sim._depart_cycle(sim._flat, g, sim._hot_batches,
+                                 jnp.asarray(mask))
+    else:
+        w_edge, _ = sim._fault_round_weights(ue_ok)
+        flat = sim._faulty_depart(sim._flat, g, sim._hot_batches,
+                                  jnp.asarray(mask), w_edge)
+    want = np.asarray(flat)
+    sim.set_flat_state(before)
+
+    assert np.all(np.isfinite(got))
+    np.testing.assert_array_equal(got[~mask], before[~mask])
+    if bucket == gids.size:
+        np.testing.assert_array_equal(got, want)
+    else:
+        np.testing.assert_allclose(got[mask], want[mask], rtol=1e-6,
+                                   atol=1e-6 * np.abs(want).max())
+
+
+def test_wave_ladder_and_counters_count_what_ran():
+    """The ladder comes from the largest cohort; a twin's first wave
+    compiles all of it; the counters add the bucket rows each wave
+    trained, the rows it kept and one run per bucket."""
+    sim, g, gids = _bucket_sim()
+    assert sim._wave_ladder == LADDER
+    for edges, _ in WAVES.values():
+        sim.replay_departure(g, _wave_mask(gids, edges))
+        assert set(sim._wave_exec[sim._depart_cycle]) == set(LADDER)
+    buckets = [b for _, b in WAVES.values()]
+    assert sim.wave_rows_trained == sum(buckets)
+    assert sim.wave_rows_kept == sum(
+        int(_wave_mask(gids, e).sum()) for e, _ in WAVES.values())
+    assert sim.wave_bucket_runs == {b: buckets.count(b) for b in LADDER}
+    # a mask that splits a cohort trains the whole buffer
+    part = _wave_mask(gids, (0,))
+    part[np.flatnonzero(part)[0]] = False
+    sim.replay_departure(g, part)
+    assert sim.wave_bucket_runs[40] == buckets.count(40) + 1
+
+
+def test_lenet_paper_ladder():
+    """The paper's 5 edges x 20 UEs: W=20 gives buckets 24, 40 and 80
+    rows below the fleet's 100; MLR's 10 x 100 gives 104, 200, 400."""
+    from repro.fl.sim import _wave_ladder
+    assert _wave_ladder(np.repeat(np.arange(5), 20)) == (24, 40, 80, 100)
+    assert _wave_ladder(np.repeat(np.arange(10), 100)) == (104, 200, 400,
+                                                           1000)
+
+
+def test_mesh_wave_keeps_the_full_buffer_program():
+    """Under a mesh every wave, one edge included, is the jitted
+    full-buffer twin with the (N_hot,) mask: the one bucket is N_hot."""
+    from jax.sharding import Mesh
+    from repro.launch.mesh import DATA_AXIS, MODEL_AXIS
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1),
+                (DATA_AXIS, MODEL_AXIS))
+    sim, g, gids = _bucket_sim(mesh=mesh)
+    n = sim._flat.shape[0]
+    assert sim._wave_ladder == (n,)
+    before = sim.flat_state()
+    mask = _wave_mask(gids, (4,))
+    sim.replay_departure(g, sim._participation_hot(mask[None])[0])
+    assert sim._wave_exec[sim._depart_cycle] == {n: sim._depart_cycle}
+    assert sim.wave_bucket_runs == {n: 1} and sim.wave_rows_trained == n
+    got = sim.flat_state()
+    assert np.all(np.isfinite(got))
+    assert not np.array_equal(got, before)

@@ -39,8 +39,8 @@ def _sync_sim(**kw):
     return sim, test
 
 
-def _service(tmp_path, **kw):
-    sim = default_service_sim(8, 2, max_staleness=2)
+def _service(tmp_path, fleet=(8, 2), **kw):
+    sim = default_service_sim(*fleet, max_staleness=2)
     cfg = ServiceConfig(segments=(Segment("iid_campus", 1.0, 30.0),
                                   Segment("iid_campus", 4.0, float("inf"))),
                         max_staleness=2, ckpt_dir=str(tmp_path / "ckpt"),
@@ -123,6 +123,26 @@ def test_op_scopes_map_the_round_and_the_wave_programs():
         "hfl.local_step", "hfl.edge_agg", "hfl.wave_select"}
 
 
+def test_op_scopes_map_the_gathered_wave_programs():
+    """Cohorts of 3, 6 and 3 rows: the wave ladder is (8, 12), so each
+    twin is built at an 8-row index and at the 12-row mask, both under
+    the twin's own name and both with the wave's scopes."""
+    sim = default_service_sim(12, 3, max_staleness=2)
+    sim._weighted_ops()
+    maps = sim.op_scopes()
+    for twin, name in [(sim._depart_cycle, "jit_depart_cycle"),
+                       (sim._faulty_depart, "jit_faulty_depart")]:
+        progs = sim._wave_programs(twin)
+        assert set(progs) == {8, 12}
+        gathered = progs[8].as_text()
+        assert gathered.split(None, 2)[1].rstrip(",") == name
+        assert "s32[8]" in gathered
+        assert set(instruction_scopes(gathered).values()) == {
+            "hfl.local_step", "hfl.edge_agg", "hfl.wave_select"}
+        assert set(maps[name].values()) == {
+            "hfl.local_step", "hfl.edge_agg", "hfl.wave_select"}
+
+
 def test_op_scopes_leave_the_run_unchanged():
     a, test = _sync_sim()
     b, _ = _sync_sim()
@@ -171,21 +191,48 @@ def test_service_run_writes_nested_spans(tmp_path):
     assert not any(_parents(spans, "hfl.checkpoint", "hfl.update"))
 
 
-def test_wave_row_counters_sum_the_masks(tmp_path):
-    svc = _service(tmp_path)
-    sim = svc.sim
+def _recorded_waves(sim):
+    """Wrap ``sim.replay_departure``: the masks of the waves it runs."""
     got = []
     depart = sim.replay_departure
 
     def record(g, mask, ue_ok=None, agg_weights=None):
         got.append(np.array(mask, bool))
         return depart(g, mask, ue_ok=ue_ok, agg_weights=agg_weights)
-
-    trained0, kept0 = sim.wave_rows_trained, sim.wave_rows_kept
-    assert (trained0, kept0) == (8, 8)        # the initial all-edge wave
     sim.replay_departure = record
+    return got
+
+
+def _bucket(sim, mask):
+    return next(b for b in sim._wave_ladder if b >= mask.sum())
+
+
+def test_wave_row_counters_sum_the_masks(tmp_path):
+    """Cohorts of 3, 6 and 3 rows, ladder (8, 12): each wave trains the
+    smallest bucket that holds its cohorts and keeps their rows."""
+    svc = _service(tmp_path, fleet=(12, 3))
+    sim = svc.sim
+    trained0, kept0 = sim.wave_rows_trained, sim.wave_rows_kept
+    assert (trained0, kept0) == (12, 12)      # the initial all-edge wave
+    got = _recorded_waves(sim)
     s = svc.run(30)
     assert got
-    assert s["wave_rows_trained"] - trained0 == sum(m.size for m in got)
+    assert s["wave_rows_trained"] - trained0 == sum(_bucket(sim, m)
+                                                    for m in got)
     assert s["wave_rows_kept"] - kept0 == sum(int(m.sum()) for m in got)
     assert 0 < s["wave_rows_kept"] < s["wave_rows_trained"]
+
+
+def test_summary_reports_the_wave_buckets(tmp_path):
+    """``summary()["wave_bucket_runs"]`` counts the waves per bucket
+    size, the initial all-edge wave in the 12-row bucket included."""
+    svc = _service(tmp_path, fleet=(12, 3))
+    assert svc.summary()["wave_bucket_runs"] == {12: 1}
+    got = _recorded_waves(svc.sim)
+    s = svc.run(30)
+    want = {12: 1}
+    for m in got:
+        want[_bucket(svc.sim, m)] = want.get(_bucket(svc.sim, m), 0) + 1
+    assert s["wave_bucket_runs"] == want
+    assert want.get(8, 0) > 0
+    assert sum(b * k for b, k in want.items()) == s["wave_rows_trained"]

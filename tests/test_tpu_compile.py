@@ -141,3 +141,61 @@ def test_sharded_cloud_aggregate_compiles_for_v5e(mesh_case, native_kernels):
     assert "tpu_custom_call" in text
     # eq. 10 meets across the data shards in one psum
     assert "all-reduce" in text
+
+
+# The MLR service cell's departure waves: 1,000 UEs in 10 cohorts of 100,
+# the 784x10 logistic regression (7,850 parameters), waves gathered into
+# the row buckets of its ladder below the whole buffer.
+MLR_BUCKETS = (104, 200, 400)
+
+
+@pytest.fixture(scope="module")
+def mlr_wave_sim():
+    from repro.core.schedule import HFLSchedule
+    from repro.fl.sim import HFLSimulator
+    from repro.models import lenet
+
+    n, m = 1000, 10
+    gids = np.repeat(np.arange(m), n // m)
+    sched = HFLSchedule(a=2, b=2, rounds=1, assoc=np.eye(m)[gids],
+                        total_delay=0.0, cloud_round_time=1.0,
+                        edge_round_time=np.ones(m))
+    rng = np.random.default_rng(0)
+    ue_data = [{"images": rng.normal(size=(2, 784)).astype(np.float32),
+                "labels": rng.integers(0, 10, 2).astype(np.int32)}
+               for _ in range(n)]
+    sim = HFLSimulator(sched, lambda p, b: lenet.logreg_loss(p, b, l2=1e-3),
+                       lenet.logreg_init(jax.random.PRNGKey(0), 784, 10),
+                       ue_data, mode="async", max_staleness=4)
+    assert sim._wave_ladder == MLR_BUCKETS + (n,)
+    sim._weighted_ops()
+    return sim
+
+
+@pytest.mark.parametrize("bucket", MLR_BUCKETS)
+@pytest.mark.parametrize("twin", ["depart_cycle", "faulty_depart"])
+def test_gathered_wave_compiles_for_v5e(one_chip, mlr_wave_sim,
+                                        native_kernels, monkeypatch, twin,
+                                        bucket):
+    """Each bucket of the wave ladder compiles for the chip with the eq. 6
+    kernel at the block width ``pick_agg_blk_f`` gives its row count, under
+    the program name the benchmark reads."""
+    from repro.fl import aggregate
+
+    monkeypatch.setattr(aggregate, "_select_kernel", lambda use_kernel: True)
+    sim = mlr_wave_sim
+    n, f = sim._flat.shape
+
+    def sds(x, dtype=None):
+        return jax.ShapeDtypeStruct(np.shape(x), dtype or x.dtype,
+                                    sharding=one_chip)
+    args = [sds(sim._flat), sds(sim._flat[0]),
+            jax.tree.map(sds, sim._hot_batches),
+            jax.ShapeDtypeStruct((bucket,), jnp.int32, sharding=one_chip)]
+    fn = sim._depart_cycle
+    if twin == "faulty_depart":
+        fn = sim._faulty_depart
+        args.append(sds(sim._hot_weights))
+    text = fn.lower(*args).compile().as_text()
+    assert text.split(None, 2)[1].rstrip(",") == f"jit_{twin}"
+    assert "tpu_custom_call" in text
