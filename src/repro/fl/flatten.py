@@ -258,9 +258,12 @@ class ShardedFlatLayout:
         return out
 
     def pad_rows(self, x):
-        """Permute+pad any per-row array/pytree (leading axis num_rows)."""
-        idx = jnp.asarray(np.maximum(self.perm, 0))
-        return jax.tree.map(lambda l: l[idx], x)
+        """Permute+pad any per-row array/pytree (leading axis num_rows).
+        Host (numpy) leaves are permuted on the host, so that placing the
+        result sends each device only its own rows."""
+        idx = np.maximum(self.perm, 0)
+        return jax.tree.map(lambda l: l[idx] if isinstance(l, np.ndarray)
+                            else l[jnp.asarray(idx)], x)
 
     def pad_weights(self, w):
         """Permute+pad the aggregation weights D_n; padding rows get

@@ -20,10 +20,11 @@ materialized only at train/eval/checkpoint boundaries.
 Pass ``mesh=`` (a ('data', 'model') mesh) and the hot loop goes
 mesh-parallel end-to-end: the buffer is carried in the padded
 ``ShardedFlatLayout`` form (UE rows group-aligned over 'data', feature
-columns over 'model' — no replication), local training vmaps over each
-shard's rows, edge aggregation runs collective-free under shard_map and
-the cloud mean costs one small psum (see repro.fl.aggregate).  Batches,
-weights and group ids are permuted/padded once at construction.
+columns over 'model' — no replication), the b-iteration edge loop (local
+training and eq. 6) runs under shard_map on each shard's own rows,
+collective-free (``_edge_cycle``), and the cloud mean costs one small
+psum (see repro.fl.aggregate).  Batches, weights and group ids are
+permuted/padded once at construction.
 
 Async mode (``mode="async"``, BEYOND-PAPER): the cloud barrier of eq. 34
 is dropped.  ``repro.core.events`` simulates each edge's cycle
@@ -61,6 +62,7 @@ from repro.core import delay, faults, stochastic
 from repro.core.schedule import HFLSchedule
 from repro.fl import aggregate, clients
 from repro.fl.flatten import FlatLayout, ShardedFlatLayout
+from repro.parallel.sharding import flat_buffer_row_spec
 
 
 def _combine_masks(a: np.ndarray, b: np.ndarray) -> np.ndarray:
@@ -90,6 +92,33 @@ def _edge_round(train, unravel, ravel, weights, group_ids, num_edges, mesh):
             return aggregate.flat_edge_aggregate(
                 trained, weights, group_ids, num_edges, mesh=mesh)
     return edge_round
+
+
+def _edge_cycle(local_steps, unravel, ravel, b, num_edges, mesh):
+    """``cycle(flat, batches, weights, group_ids)``: the b-iteration edge
+    loop of a cloud round (``_edge_round`` b times) over the flat buffer.
+
+    Under a mesh the loop runs under shard_map over the row axis: each
+    device trains and edge-aggregates only the rows it holds, with the
+    one-chip body (the layout keeps every edge whole on one row shard, so
+    its local eq. 6 means are the global ones).  A feature-sharded buffer
+    is first gathered to whole rows, inside ``hfl.local_step``."""
+    def cycle(flat, batches, weights, group_ids):
+        return jax.lax.fori_loop(
+            0, b, _edge_round(_vmapped(local_steps, batches), unravel, ravel,
+                              weights, group_ids, num_edges, None), flat)
+    if mesh is None:
+        return cycle
+    rows = flat_buffer_row_spec(mesh)
+    sharded = jax.shard_map(cycle, mesh=mesh, in_specs=(rows,) * 4,
+                            out_specs=rows, check_vma=False)
+
+    def row_cycle(flat, batches, weights, group_ids):
+        with jax.named_scope("hfl.local_step"):
+            flat = jax.lax.with_sharding_constraint(
+                flat, NamedSharding(mesh, rows))
+        return sharded(flat, batches, weights, group_ids)
+    return row_cycle
 
 
 def _wave_ladder(group_ids: np.ndarray) -> tuple:
@@ -387,22 +416,27 @@ class HFLSimulator:
 
         local_gd = clients.gd_local_steps(loss_fn, a, lr)
         local_dane = clients.dane_local_steps(loss_fn, a, lr, mu_prox=dane_mu)
+        cycle = _edge_cycle(local_gd, unravel, ravel, b, M, mesh)
 
         def cloud_round(flat, batches):
             # The whole b-iteration edge loop carries the flat buffer;
             # unravel/ravel around local training are jit-fused reshapes,
-            # and each aggregation event is a single dispatch (per-device
-            # under shard_map when a mesh is threaded through).
-            def train(p):
-                if solver == "dane":
-                    g_bar = clients.global_gradient(loss_fn, p, batches, weights)
+            # and each aggregation event is a single dispatch (GD under a
+            # mesh: each device's own rows, under shard_map).  DANE's
+            # global gradient spans every row, so it keeps the partitioned
+            # program.
+            if solver == "dane":
+                def train(p):
+                    g_bar = clients.global_gradient(loss_fn, p, batches,
+                                                    weights)
                     return jax.vmap(lambda pp, bb: local_dane(pp, bb, g_bar))(
                         p, batches)
-                return jax.vmap(local_gd)(p, batches)
 
-            flat = jax.lax.fori_loop(
-                0, b, _edge_round(train, unravel, ravel, weights, group_ids,
-                                  M, mesh), flat)
+                flat = jax.lax.fori_loop(
+                    0, b, _edge_round(train, unravel, ravel, weights,
+                                      group_ids, M, mesh), flat)
+            else:
+                flat = cycle(flat, batches, weights, group_ids)
             with jax.named_scope("hfl.cloud_agg"):
                 return aggregate.flat_cloud_aggregate(flat, weights,
                                                       mesh=mesh)
@@ -477,11 +511,10 @@ class HFLSimulator:
         local_gd = clients.gd_local_steps(self.loss_fn, a, self.lr)
         wave = _departure_wave(local_gd, unravel, ravel, b, group_ids, M,
                                mesh)
+        cycle = _edge_cycle(local_gd, unravel, ravel, b, M, mesh)
 
         def faulty_cloud_round(flat, batches, w_edge, w_cloud):
-            flat = jax.lax.fori_loop(
-                0, b, _edge_round(_vmapped(local_gd, batches), unravel,
-                                  ravel, w_edge, group_ids, M, mesh), flat)
+            flat = cycle(flat, batches, w_edge, group_ids)
             with jax.named_scope("hfl.cloud_agg"):
                 return aggregate.flat_cloud_aggregate(flat, w_cloud,
                                                       mesh=mesh)
@@ -565,6 +598,26 @@ class HFLSimulator:
             (self._faulty_cloud_round,
              self._faulty_depart) = self._weighted_ops_cache
         return self._weighted_ops_cache
+
+    def shard_rows(self) -> dict:
+        """What the hot layout puts on each device, keyed by device id:
+        ``rows`` (real UE rows held), ``pad_rows`` (zero-weight padding
+        rows held), ``flat_bytes`` and ``batch_bytes`` (resident bytes of
+        the flat buffer's and the batches' shards on it).  One chip holds
+        every row and no padding."""
+        perm = (self._slayout.perm if self._slayout is not None
+                else np.arange(self._flat.shape[0]))
+        out = {}
+        for s in self._flat.addressable_shards:
+            held = perm[s.index[0]]
+            real = int(np.sum(held >= 0))
+            out[s.device.id] = {"rows": real, "pad_rows": held.size - real,
+                                "flat_bytes": int(s.data.nbytes),
+                                "batch_bytes": 0}
+        for leaf in jax.tree.leaves(self._hot_batches):
+            for s in leaf.addressable_shards:
+                out[s.device.id]["batch_bytes"] += int(s.data.nbytes)
+        return out
 
     def op_scopes(self) -> dict:
         """``{program: {instruction: scope}}`` for every program this
